@@ -2,9 +2,10 @@
 
 Pins: the shared cost model reproduces the BENCH-documented per-row
 traffic figures and agrees with `choose_kstep_block`'s block choice;
-the roofline fraction is reported for every instrumented solver path
+modeled GB/s is reported for every instrumented solver path
 (roll / pallas 1-step / k-fused / comp / sharded) plus the serve
-execute span; memory sampling keeps the None-on-unsupported contract
+execute span, and the roofline fraction against the chip's published
+peak (None off the TPU); memory sampling keeps the None-on-unsupported contract
 and the watermark/warn machinery works against a fake stats provider.
 """
 
@@ -16,6 +17,17 @@ import pytest
 from wavetpu.core.problem import Problem
 from wavetpu.obs import perf, telemetry, tracing
 from wavetpu.obs.registry import MetricsRegistry, get_registry
+
+
+def _fake_device(monkeypatch, platform, kind):
+    """Make obs/perf see one device of `platform`/`kind` as device 0."""
+    import types
+
+    dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+    monkeypatch.setitem(
+        __import__("sys").modules, "jax",
+        types.SimpleNamespace(devices=lambda: [dev]),
+    )
 
 
 class TestCostModel:
@@ -85,21 +97,34 @@ class TestCostModel:
                                with_field=True) is None
 
     def test_solve_perf_fields(self, monkeypatch):
-        monkeypatch.setenv("WAVETPU_PEAK_GBPS", "250")
+        _fake_device(monkeypatch, "tpu", "TPU v5 lite")
         rf = perf.solve_perf(40.0, "kfused", k=4, n=512)
         assert rf["model_bytes_per_cell"] == 8.0
         assert rf["model_gbps"] == 320.0
-        assert rf["peak_gbps"] == 250.0
-        assert rf["roofline_fraction"] == round(320.0 / 250.0, 4)
+        assert rf["peak_gbps"] == 819.0  # published v5e HBM bandwidth
+        assert rf["roofline_fraction"] == round(320.0 / 819.0, 4)
         assert rf["arithmetic_intensity"] == round(15.0 / 8.0, 4)
         assert perf.solve_perf(0.0, "kfused", k=4, n=512) is None
+
+    def test_no_roofline_off_the_tpu(self, monkeypatch):
+        _fake_device(monkeypatch, "cpu", "cpu")
+        rf = perf.solve_perf(40.0, "kfused", k=4, n=512)
+        assert rf["model_gbps"] == 320.0
+        assert rf["peak_gbps"] is None
+        assert rf["roofline_fraction"] is None  # not measured
+
+    def test_unknown_tpu_kind_raises(self, monkeypatch):
+        _fake_device(monkeypatch, "tpu", "TPU v99")
+        with pytest.raises(ValueError, match="TPU v99"):
+            perf.peak_gbps()
 
 
 class TestRooflineRecording:
     def test_all_instrumented_paths_report_a_fraction(self):
         """Acceptance pin: after one solve per family (roll, pallas
         1-step, k-fused, comp, sharded), the process registry holds a
-        positive roofline fraction for every path label."""
+        positive modeled GB/s for every path label (and, on CPU, no
+        roofline fraction)."""
         from wavetpu.kernels import stencil_pallas
         from wavetpu.solver import kfused, kfused_comp, leapfrog, sharded
 
@@ -116,12 +141,15 @@ class TestRooflineRecording:
             "wavetpu_solve_roofline_fraction", "", ("path",)
         )
         # 1-step variable-c: the ParamStep kernel must model the extra
-        # field stream (16 B/cell, not 12) - gauge ratio pins it.
+        # field stream (16 B/cell, not 12) - gauge ratio pins it.  Big
+        # enough that the 3-decimal rounding of model_gbps stays small
+        # against the rate on a loaded CPU (N=8/3 read 16.66 under xdist).
         from wavetpu.kernels import stencil_ref
 
-        field = stencil_ref.make_preset_c2tau2_field(p, "constant")
+        pv = Problem(N=32, timesteps=100)
+        field = stencil_ref.make_preset_c2tau2_field(pv, "constant")
         leapfrog.solve(
-            p, step_fn=stencil_ref.make_variable_c_step(field),
+            pv, step_fn=stencil_ref.make_variable_c_step(field),
             compute_errors=False,
         )
         reg = get_registry()
@@ -137,9 +165,12 @@ class TestRooflineRecording:
             "wavetpu_solve_gbps", "", ("path",),
             buckets=perf._GBPS_BUCKETS,
         )
+        gbps = reg.gauge("wavetpu_solve_model_gbps", "", ("path",))
         for path in ("leapfrog", "compensated", "kfused", "kfused_comp",
                      "sharded"):
-            assert g.value(path=path) > 0.0, path
+            assert gbps.value(path=path) > 0.0, path
+            # CPU has no roofline: the fraction gauge stays unset.
+            assert g.value(path=path) == 0.0, path
             assert h.count(path=path) >= 1, path
 
     def test_serve_execute_span_carries_roofline_attrs(self, tmp_path):
@@ -163,10 +194,10 @@ class TestRooflineRecording:
         attrs = ex[-1]["attrs"]
         assert attrs["model_bytes_per_cell"] == 12.0
         assert attrs["model_gbps"] > 0.0
-        assert 0.0 < attrs["roofline_fraction"]
+        assert attrs["roofline_fraction"] is None  # CPU: not measured
         # and the server registry carries the same gauges
         assert eng.registry.gauge(
-            "wavetpu_solve_roofline_fraction", "", ("path",)
+            "wavetpu_solve_model_gbps", "", ("path",)
         ).value(path="roll") > 0.0
 
 
@@ -292,7 +323,7 @@ class TestProfileSubcommand:
         spans = [json.loads(line) for line in open(trace_path)]
         cs = [s for s in spans if s.get("kind") == "cli.solve"]
         assert cs and cs[-1]["attrs"]["model_gbps"] > 0
-        assert cs[-1]["attrs"]["roofline_fraction"] > 0
+        assert cs[-1]["attrs"]["roofline_fraction"] is None  # CPU
         assert os.path.exists(
             os.path.join(out, "telemetry", "compile_ledger.jsonl")
         )

@@ -1,0 +1,117 @@
+"""The main path's kernels compile for a v5e chip that is described, not
+attached (TPU compiler rehearsal; no chip needed, nothing runs).
+
+Interpret-mode tests cannot see what Mosaic refuses: unaligned slices,
+scoped-VMEM overflows, a kernel that cannot be partitioned.  These
+compiles can.  The topology is described inside a module fixture, never
+at import: only one process may load the TPU library, and each xdist
+worker imports every test file.  Keep every such compile in this one
+file, so one worker loads the library.  JAX's compilation cache stays off
+around them (an entry written here cannot be read back without a chip).
+"""
+
+import functools
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from wavetpu.core.problem import Problem
+from wavetpu.kernels import stencil_pallas
+
+N = 512
+K = 4
+HBM_BYTES = 16 * 10**9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described v5e:2x2, with the chip's own config for the module:
+    the compilation cache off, and 32-bit mode as on the chip (the suite
+    turns x64 on, under which Mosaic refuses pltpu.roll's i64 shift)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    saved = {name: getattr(jax.config, name) for name in (
+        "jax_enable_compilation_cache", "jax_enable_x64")}
+    for name in saved:
+        jax.config.update(name, False)
+    yield desc
+    for name, value in saved.items():
+        jax.config.update(name, value)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _check(compiled):
+    assert "tpu_custom_call" in compiled.as_text()  # the Mosaic kernel
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, total
+
+
+def _f32(shape, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+
+def test_leapfrog_step(one_chip):
+    problem = Problem(N=N, timesteps=1000)
+    field = _f32((N, N, N), one_chip)
+    step = functools.partial(stencil_pallas.leapfrog_step, problem=problem)
+    _check(jax.jit(step).lower(field, field).compile())
+
+
+def test_fused_kstep(one_chip):
+    problem = Problem(N=N, timesteps=1000)
+    field, plane = _f32((N, N, N), one_chip), _f32((N, N), one_chip)
+    kstep = functools.partial(
+        stencil_pallas.fused_kstep, k=K, coeff=problem.a2tau2,
+        inv_h2=problem.inv_h2,
+    )
+    _check(jax.jit(kstep).lower(
+        field, field, plane, plane, _f32((K, N), one_chip)
+    ).compile())
+
+
+def test_fused_kstep_comp(one_chip):
+    problem = Problem(N=N, timesteps=1000)
+    field, plane = _f32((N, N, N), one_chip), _f32((N, N), one_chip)
+    kstep = functools.partial(
+        stencil_pallas.fused_kstep_comp, k=K, coeff=problem.a2tau2,
+        inv_h2=problem.inv_h2,
+    )
+    _check(jax.jit(kstep).lower(
+        field, field, field, plane, plane, _f32((K, N), one_chip)
+    ).compile())
+
+
+def test_fused_kstep_comp_sharded_xy_four_chips(topo):
+    """The y-sharded compensated onion (fused_kstep_comp_sharded_xy) in
+    the (2,2,1) mesh program that runs it: bootstrap plus one k=4 block
+    (timesteps 5), each of the four shards a (256, 256, 512) block."""
+    from wavetpu.core.grid import build_mesh
+    from wavetpu.solver import kfused_comp
+
+    problem = Problem(N=N, timesteps=1 + K)
+    mesh = build_mesh((2, 2, 1), topo.devices[:4])
+    runner = kfused_comp._make_sharded_runner(
+        problem, mesh, (2, 2), jnp.float32, jnp.float32, True, K,
+        True, problem.timesteps, None, None, False,
+    )
+    compiled = runner.lower().compile()
+    _check(compiled)
+    assert compiled.output_shardings[0].mesh.devices.size == 4

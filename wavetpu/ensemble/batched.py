@@ -846,19 +846,19 @@ class EnsembleSolver:
         `progcache.aot_capability()` first."""
         if self._exec is None:
             return None
-        from jax.experimental import serialize_executable as se
+        from wavetpu.serve import progcache
 
-        return se.serialize(self._exec)
+        return progcache.serialize_executable(self._exec)
 
     def adopt_executable(self, payload) -> float:
         """Install a deserialized executable (the disk tier's warm
         path - skips lower+compile entirely); returns the deserialize
         wall seconds.  Raises on an incompatible payload - the caller
         counts it a cache miss and compiles fresh."""
-        from jax.experimental import serialize_executable as se
+        from wavetpu.serve import progcache
 
         t0 = time.perf_counter()
-        self._exec = se.deserialize_and_load(*payload)
+        self._exec = progcache.load_executable(payload)
         self.compile_seconds = time.perf_counter() - t0
         return self.compile_seconds
 
@@ -873,9 +873,9 @@ class EnsembleSolver:
         t0 = time.perf_counter()
         out = self._exec(*args)
         jax.block_until_ready(out)
-        # Readback proves execution on remote backends (the same reasoning
-        # as leapfrog._timed_compile_run's sync): the (B, T+1) error
-        # block is the smallest always-present output.
+        # Readback proves execution (the same reasoning as
+        # leapfrog._timed_compile_run's sync): the (B, T+1) error block
+        # is the smallest always-present output.
         np.asarray(out[2])
         solve_s = time.perf_counter() - t0
         return out, init_s, solve_s

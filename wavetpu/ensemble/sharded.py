@@ -97,7 +97,6 @@ class ShardedEnsembleSolver:
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from wavetpu import compat
         from wavetpu.core.grid import AXIS_NAMES
         from wavetpu.kernels import stencil_ref
         from wavetpu.solver import sharded
@@ -200,7 +199,7 @@ class ShardedEnsembleSolver:
               mex, mey, mez)
 
         state_spec = P(None, *AXIS_NAMES)
-        sharded_fn = compat.shard_map(
+        sharded_fn = jax.shard_map(
             local_batch,
             mesh=mesh,
             in_specs=(
@@ -270,17 +269,17 @@ class ShardedEnsembleSolver:
         (the two program types share the disk tier)."""
         if self._exec is None:
             return None
-        from jax.experimental import serialize_executable as se
+        from wavetpu.serve import progcache
 
-        return se.serialize(self._exec)
+        return progcache.serialize_executable(self._exec)
 
     def adopt_executable(self, payload) -> float:
         """Install a deserialized executable; see
         `batched.EnsembleSolver.adopt_executable`."""
-        from jax.experimental import serialize_executable as se
+        from wavetpu.serve import progcache
 
         t0 = time.perf_counter()
-        self._exec = se.deserialize_and_load(*payload)
+        self._exec = progcache.load_executable(payload)
         self.compile_seconds = time.perf_counter() - t0
         return self.compile_seconds
 

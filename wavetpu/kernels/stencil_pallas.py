@@ -43,7 +43,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from wavetpu.core.problem import Problem
-from wavetpu import compat
 from wavetpu.kernels import stencil_ref
 
 # Per-core VMEM working-set budget (bytes) used to pick block_x: the
@@ -212,7 +211,7 @@ def _fused_step(u_prev, u, *, inv_h2, alpha=2.0, beta=1.0, coeff=None,
         in_specs=in_specs,
         out_specs=slab,
         out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
-        compiler_params=compat.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(*operands)
 
@@ -497,7 +496,7 @@ def sharded_fused_step(u_prev, u, ghosts, offsets, n_global, *, inv_h2,
         in_specs=in_specs,
         out_specs=slab,
         out_shape=_out_struct(u),
-        compiler_params=compat.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(*operands)
 
@@ -536,7 +535,7 @@ def sharded_compensated_step(u, v, carry, ghosts, offsets, n_global, *,
         in_specs=in_specs,
         out_specs=[slab, slab, slab],
         out_shape=[out, out, out],
-        compiler_params=compat.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(*operands)
 
@@ -598,7 +597,7 @@ def compensated_step(u, v, carry, problem: Problem, coeff=None, *,
         in_specs=[slab, slab, slab, lo, hi],
         out_specs=[slab, slab, slab],
         out_shape=[out, out, out],
-        compiler_params=compat.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(v, carry, u, u, u)
 
@@ -894,7 +893,7 @@ def fused_kstep(u_prev, u, syz, rsyz, sxct, *, k, coeff, inv_h2,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_KSTEP_VMEM_LIMIT
         ),
         interpret=interpret,
@@ -1148,7 +1147,7 @@ def fused_kstep_comp(u, v, carry, syz, rsyz, sxct, *, k, coeff, inv_h2,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_KSTEP_COMP_VMEM_LIMIT
         ),
         interpret=interpret,
@@ -1354,7 +1353,7 @@ def fused_kstep_comp_sharded(u, v, carry, u_ghosts, v_ghosts, syz, rsyz,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_KSTEP_COMP_VMEM_LIMIT
         ),
         interpret=interpret,
@@ -1578,7 +1577,7 @@ def fused_kstep_comp_sharded_xy(u_ext, v_ext, carry, u_ghosts, v_ghosts,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_KSTEP_COMP_VMEM_LIMIT
         ),
         interpret=interpret,
@@ -1769,7 +1768,7 @@ def fused_kstep_sharded(u_prev, u, prev_ghosts, cur_ghosts, syz, rsyz, sxct,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_KSTEP_VMEM_LIMIT
         ),
         interpret=interpret,
@@ -1959,7 +1958,7 @@ def fused_kstep_padded(ext_prev, ext_cur, n_real, syz, rsyz, sxct, *,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_KSTEP_VMEM_LIMIT
         ),
         interpret=interpret,
@@ -2153,7 +2152,7 @@ def fused_kstep_sharded_xy(u_prev_ext, u_ext, prev_ghosts, cur_ghosts,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_KSTEP_VMEM_LIMIT
         ),
         interpret=interpret,

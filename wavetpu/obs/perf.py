@@ -23,10 +23,9 @@ modeled traffic follows the block the kernel actually runs).  From it,
 
 `metrics.record_solve` stamps these on every instrumented solve
 (gauges + per-path GB/s histograms), and the serve engine attaches the
-same attrs to its `serve.execute` spans.  `peak_gbps` defaults to the
-measured pallas copy bandwidth on this repo's v5e (~250 GB/s, see
-kernels/stencil_pallas.py's k-step section comment) and is overridable
-via WAVETPU_PEAK_GBPS for other parts.
+same attrs to its `serve.execute` spans.  `peak_gbps` is the chip's
+published HBM bandwidth, looked up by `device_kind`; off the TPU there
+is no roofline, and the fraction is None ("not measured").
 
 DEVICE-MEMORY OBSERVABILITY.  `memory_snapshot()` reads
 `device.memory_stats()` (None on backends without it - e.g. the CPU
@@ -81,37 +80,33 @@ from wavetpu.obs.registry import MetricsRegistry, get_registry
 # are the number that matters and flops just document WHY.
 FLOPS_PER_CELL = {"standard": 15.0, "compensated": 21.0}
 
-# Measured pallas copy bandwidth on this repo's v5e (the 1-step wall
-# analysis in stencil_pallas.py's k-step section comment); CPU/other
-# backends get a nominal figure - their fractions exercise the plumbing,
-# not the analysis.
-DEFAULT_PEAK_GBPS = {"tpu": 250.0}
-FALLBACK_PEAK_GBPS = 25.0
+# Published HBM bandwidth of one chip, keyed by jax's `device_kind`.
+# Source: Google Cloud documentation, "TPU v5e" (16 GB of HBM at
+# 819 GB/s).  A TPU kind missing here is an error, not a default.
+PEAK_GBPS_BY_KIND = {"TPU v5 lite": 819.0}
 
 # Serve-layer dtype names -> state itemsize (the engine's roofline
 # call resolves its ProgramKey dtype string through this).
 DTYPE_ITEMSIZE = {"f32": 4, "f64": 8, "bf16": 2}
 
 
-def peak_gbps() -> float:
-    """The roofline ceiling: WAVETPU_PEAK_GBPS env override, else the
-    backend default (measured copy bandwidth on TPU, nominal elsewhere)."""
-    env = os.environ.get("WAVETPU_PEAK_GBPS")
-    if env:
-        try:
-            v = float(env)
-            if v > 0:
-                return v
-        except ValueError:
-            pass
-    backend = None
+def peak_gbps() -> Optional[float]:
+    """The roofline ceiling of device 0: its published HBM GB/s, or None
+    off the TPU (and in jax-free processes), where no roofline exists.
+    Raises ValueError for a TPU kind the table does not know."""
     jax = sys.modules.get("jax")
-    if jax is not None:
-        try:
-            backend = jax.default_backend()
-        except Exception:
-            backend = None
-    return DEFAULT_PEAK_GBPS.get(backend, FALLBACK_PEAK_GBPS)
+    if jax is None:
+        return None
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return None
+    if dev.device_kind not in PEAK_GBPS_BY_KIND:
+        raise ValueError(
+            f"no published HBM bandwidth for TPU kind "
+            f"{dev.device_kind!r}; add it to PEAK_GBPS_BY_KIND with its "
+            f"source"
+        )
+    return PEAK_GBPS_BY_KIND[dev.device_kind]
 
 
 def _is_comp_onion(path: str, scheme: str) -> bool:
@@ -248,7 +243,9 @@ def solve_perf(
         "model_bytes_per_cell": round(bpc, 4),
         "model_gbps": round(model_gbps, 3),
         "peak_gbps": peak,
-        "roofline_fraction": round(model_gbps / peak, 4),
+        "roofline_fraction": (
+            None if peak is None else round(model_gbps / peak, 4)
+        ),
         "flops_per_cell": fpc,
         "arithmetic_intensity": round(fpc / bpc, 4),
     }
@@ -267,11 +264,12 @@ def record_roofline(registry: Optional[MetricsRegistry], path: str,
     if perf is None:
         return None
     reg = registry if registry is not None else get_registry()
-    reg.gauge(
-        "wavetpu_solve_roofline_fraction",
-        "modeled-GB/s share of the memory roofline, most recent solve",
-        ("path",),
-    ).set(perf["roofline_fraction"], path=path)
+    if perf["roofline_fraction"] is not None:
+        reg.gauge(
+            "wavetpu_solve_roofline_fraction",
+            "modeled-GB/s share of the memory roofline, most recent "
+            "solve", ("path",),
+        ).set(perf["roofline_fraction"], path=path)
     reg.gauge(
         "wavetpu_solve_model_gbps",
         "achieved HBM GB/s under the path's traffic model, most recent "

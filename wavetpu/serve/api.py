@@ -1292,13 +1292,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         print(_USAGE, file=sys.stderr)
         return 2
 
-    import os
-
     import jax
 
-    platform = flags.get("platform") or os.environ.get("JAX_PLATFORMS")
-    if platform and platform != jax.config.jax_platforms:
-        jax.config.update("jax_platforms", platform)
+    from wavetpu import jaxcache
+
+    if "platform" in flags:
+        jax.config.update("jax_platforms", flags["platform"])
+    jaxcache.configure()
 
     httpd, state = build_server(
         host=host, port=port, bucket_sizes=buckets, max_batch=max_batch,

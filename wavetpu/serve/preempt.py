@@ -459,13 +459,13 @@ class ChunkRunner:
         EnsembleSolver.executable_payload)."""
         if self._boot_exec is None or not self._path._compiled:
             return None
-        from jax.experimental import serialize_executable as se
+        from wavetpu.serve import progcache
 
         return {
             "format": 1,
-            "boot": se.serialize(self._boot_exec),
+            "boot": progcache.serialize_executable(self._boot_exec),
             "chunks": {
-                int(length): se.serialize(compiled)
+                int(length): progcache.serialize_executable(compiled)
                 for length, compiled in self._path._compiled.items()
             },
         }
@@ -475,11 +475,11 @@ class ChunkRunner:
         returns the deserialize wall seconds.  Raises on an
         incompatible payload - the caller counts a miss and compiles
         fresh."""
-        from jax.experimental import serialize_executable as se
+        from wavetpu.serve import progcache
 
         t0 = time.perf_counter()
         self._boot_builders()
-        boot_exec = se.deserialize_and_load(*payload["boot"])
+        boot_exec = progcache.load_executable(payload["boot"])
         chunk_execs = {}
         for length, blob in payload["chunks"].items():
             length = int(length)
@@ -490,7 +490,7 @@ class ChunkRunner:
                 self._path._jit[length] = self._path._build_runner(
                     length, None
                 )
-            chunk_execs[length] = se.deserialize_and_load(*blob)
+            chunk_execs[length] = progcache.load_executable(blob)
         self._boot_exec = boot_exec
         self._path._compiled.update(chunk_execs)
         spent = time.perf_counter() - t0

@@ -23,18 +23,18 @@ fingerprint, length, and checksum - a truncated, stale-fingerprint, or
 cross-version entry is a COUNTED miss that falls through to a fresh
 compile, never a crash and never a circuit-breaker feed.
 
-The payload is `jax.experimental.serialize_executable.serialize` of the
-lowered-and-compiled ensemble program; `aot_capability()` probes once
-per process whether this jaxlib round-trips it (serialize ->
-deserialize -> execute a tiny program) and the verdict rides /metrics
-next to the vmap probes.  Where the probe fails, the cache falls back
-to JAX's persistent compilation cache (`jax_compilation_cache_dir`)
-scoped to DIR/xla - compiles are then transparently fast but not
-adoptable, so they still count as engine misses; the mode is visible in
-the same probe surface.  In AOT mode the DIR/xla cache rides along
-anyway: the incidental jits around the ensemble program (watchdog
-reductions, padding helpers) are real cold-start cost with no
-executable object to adopt, and the XLA cache is exactly their shape.
+The payload is `serialize_executable` (below) of the lowered-and-
+compiled ensemble program; `aot_capability()` probes once per process
+whether this jaxlib round-trips it (serialize -> deserialize -> execute
+a tiny program) and the verdict rides /metrics next to the vmap probes.
+Where the probe fails, JAX's persistent compilation cache (placed by
+`wavetpu.jaxcache`) is the only persistence left - compiles are then
+transparently fast but not adoptable, so they still count as engine
+misses; the mode is visible in the same probe surface.  In AOT mode the
+XLA cache rides along anyway: the incidental jits around the ensemble
+program (watchdog reductions, padding helpers) are real cold-start cost
+with no executable object to adopt, and the XLA cache is exactly their
+shape.
 
 Size is bounded by `--program-cache-max-bytes`: LRU by access time
 (entry mtime, refreshed via os.utime on every hit), oldest evicted
@@ -62,6 +62,7 @@ import threading
 import time
 from typing import List, Optional, Sequence, Tuple
 
+from wavetpu import jaxcache
 from wavetpu.obs import ledger as compile_ledger
 
 MAGIC = b"WTPC0001"
@@ -106,42 +107,66 @@ _AOT_PROBE: Optional[Tuple[bool, Optional[str]]] = None
 _probe_lock = threading.Lock()
 
 
+def serialize_executable(compiled) -> tuple:
+    """`(payload, in_tree, out_tree, device_ids)` of a compiled program:
+    `serialize_executable.serialize`'s triple plus the ids of the
+    devices the program runs on, in its own order.  Without them a
+    load would place the program on every visible device, which fails
+    for a one-device program on a host with several."""
+    from jax.experimental import serialize_executable as se
+
+    payload, in_tree, out_tree = se.serialize(compiled)
+    ids = [d.id for d in compiled.runtime_executable().local_devices()]
+    return payload, in_tree, out_tree, ids
+
+
+def load_executable(blob):
+    """Inverse of `serialize_executable`: the compiled program, loaded
+    onto the devices it was compiled for.  Raises on an incompatible
+    payload (a missing device included)."""
+    import jax
+    from jax.experimental import serialize_executable as se
+
+    payload, in_tree, out_tree, ids = blob
+    by_id = {d.id: d for d in jax.devices()}
+    return se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in ids],
+    )
+
+
 def aot_capability() -> Tuple[bool, Optional[str]]:
     """Can this jaxlib serialize, deserialize, AND execute a compiled
-    executable?  Probed once per process with a tiny jit (the
-    `vmap_capability` discipline: record the verdict, never raise), and
-    surfaced in /metrics via `probe_results()` - a replica silently
-    running the XLA-cache fallback must be visible from the outside."""
+    executable?  Probed once per process with a tiny jit through the
+    same `serialize_executable` / `load_executable` pair the engines
+    store and adopt with (the `vmap_capability` discipline: record the
+    verdict, never raise), and surfaced in /metrics via
+    `probe_results()` - a replica silently running the XLA-cache
+    fallback must be visible from the outside."""
     global _AOT_PROBE
     with _probe_lock:
         if _AOT_PROBE is not None:
             return _AOT_PROBE
-        restore = None
-        try:
-            import jax
-            import jax.numpy as jnp
-            from jax.experimental import serialize_executable as se
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental.compilation_cache import compilation_cache
 
-            # The probe must compile OUTSIDE the persistent compilation
-            # cache: an XLA-cache-served executable serializes but
-            # fails deserialize_and_load ("Symbols not found"), which
-            # would flip every restarted replica into fallback mode -
-            # exactly the processes the AOT tier exists for.
-            try:
-                restore = jax.config.jax_enable_compilation_cache
-                jax.config.update("jax_enable_compilation_cache", False)
-            except Exception:
-                restore = None
+        # The probe must compile OUTSIDE the persistent compilation
+        # cache: an XLA-cache-served executable serializes but fails
+        # deserialize_and_load ("Symbols not found"), which would flip
+        # every restarted replica into fallback mode - exactly the
+        # processes the AOT tier exists for.
+        restore = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
             f = jax.jit(lambda x: x * 2.0 + 1.0)
             compiled = f.lower(jnp.zeros((4,), jnp.float32)).compile()
-            triple = se.serialize(compiled)
             # Round-trip through pickle exactly as an entry file does -
             # a PyTreeDef that serializes but does not pickle would
             # pass a weaker probe and still corrupt every store.
-            payload, in_tree, out_tree = pickle.loads(
-                pickle.dumps(triple)
+            again = load_executable(
+                pickle.loads(pickle.dumps(serialize_executable(compiled)))
             )
-            again = se.deserialize_and_load(payload, in_tree, out_tree)
             out = again(jnp.ones((4,), jnp.float32))
             if float(out[0]) != 3.0:
                 raise RuntimeError(
@@ -151,15 +176,11 @@ def aot_capability() -> Tuple[bool, Optional[str]]:
             verdict = (True, None)
         except Exception as e:  # recorded, never raised
             verdict = (False, f"{type(e).__name__}: {e}")
-        if restore is not None:
-            try:
-                import jax
-
-                jax.config.update(
-                    "jax_enable_compilation_cache", restore
-                )
-            except Exception:
-                pass
+        finally:
+            jax.config.update("jax_enable_compilation_cache", restore)
+            # JAX decides once, at a compile, whether its cache is in
+            # use: let the next compile decide again with the cache on.
+            compilation_cache.reset_cache()
         _AOT_PROBE = verdict
         return verdict
 
@@ -176,42 +197,7 @@ def probe_results() -> list:
     }]
 
 
-# --------------------------------------- XLA persistent-cache fallback
-
-
-def enable_xla_cache(directory: str) -> bool:
-    """Scope JAX's persistent compilation cache to `directory` (the
-    fallback tier where AOT serialization is unavailable, and the solo
-    CLI's mechanism - solo solvers jit internally, so there is no
-    executable object to adopt).  Thresholds are zeroed so CI-scale
-    compiles cache too.  Returns False (recorded, not raised) on any
-    config the installed jax does not know."""
-    try:
-        import jax
-
-        os.makedirs(directory, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", directory)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", 0.0
-        )
-        jax.config.update(
-            "jax_persistent_cache_min_entry_size_bytes", -1
-        )
-        try:
-            # If ANY compile ran before this config landed (the AOT
-            # probe, a warmup jit), jax initialized its cache as
-            # disabled and silently ignores the new dir; a reset makes
-            # the next compile re-read the config.  Private API,
-            # best-effort: without it the cache still works when
-            # configured before first compile.
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:
-            pass
-        return True
-    except Exception:
-        return False
+# --------------------------------------- XLA persistent-cache hits
 
 
 class XlaCacheHitCounter:
@@ -294,13 +280,9 @@ class ProgramCache:
         # cold-start cost with no adoptable executable); where the AOT
         # probe fails it IS the persistence mechanism (and gets the hit
         # counter, so fallback-mode compiles can be attributed).
-        # Configured BEFORE the probe compiles anything - see
-        # enable_xla_cache on why ordering matters.
         ok, _why = aot_capability()
         self.aot_ok = ok
-        self.xla_cache = enable_xla_cache(
-            os.path.join(directory, "xla")
-        )
+        self.xla_cache = jaxcache.configure() is not None
         self.xla_fallback = bool(self.xla_cache and not ok)
         # The hit counter serves two masters: fallback-mode ledger
         # attribution (`source: disk` when the XLA cache served a

@@ -2,7 +2,7 @@
 
 PR 2 made *solves* survive faults (supervisor, checkpoint rotation,
 watchdog); this module is the serve-stack half of that contract.  The
-scheduler, engine, and HTTP layer share a small failure taxonomy so a
+scheduler, engine, and HTTP layer share a small failure classification so a
 client can tell "retry me" from "your fault" from "too late":
 
  * `DeadlineExceededError`  -> HTTP 504.  The request's `deadline_ms`

@@ -57,7 +57,6 @@ import numpy as np
 from jax import lax
 
 from wavetpu.core.problem import Problem
-from wavetpu import compat
 from wavetpu.kernels import stencil_pallas, stencil_ref
 from wavetpu.obs import metrics as obs_metrics
 from wavetpu.solver import kfused, leapfrog
@@ -656,7 +655,7 @@ def _make_sharded_runner(problem, mesh, grid, dtype, v_dtype, carry_on, k,
                 fargs[0] if has_field else None,
             )
 
-        local_fn = compat.shard_map(
+        local_fn = jax.shard_map(
             local_chunk, mesh=mesh,
             in_specs=(state_spec, state_spec,
                       state_spec if carry_on else None,
@@ -710,7 +709,7 @@ def _make_sharded_runner(problem, mesh, grid, dtype, v_dtype, carry_on, k,
                 jnp.concatenate([zero, r1, rows_r]),
             )
 
-        local_fn = compat.shard_map(
+        local_fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(state_spec, rows_spec, plane_spec, plane_spec)
             + field_specs,
@@ -745,7 +744,7 @@ def _make_sharded_runner(problem, mesh, grid, dtype, v_dtype, carry_on, k,
             jnp.concatenate([head, rows_r]),
         )
 
-    local_fn = compat.shard_map(
+    local_fn = jax.shard_map(
         local_resume, mesh=mesh,
         in_specs=(state_spec, state_spec,
                   state_spec if carry_on else None,

@@ -48,7 +48,6 @@ from jax.sharding import PartitionSpec as P
 from wavetpu.comm import halo
 from wavetpu.core.grid import AXIS_NAMES, Topology, build_mesh, choose_mesh_shape
 from wavetpu.core.problem import Problem
-from wavetpu import compat
 from wavetpu.kernels import stencil_pallas, stencil_ref
 from wavetpu.obs import metrics as obs_metrics
 from wavetpu.solver.leapfrog import SolveResult
@@ -571,7 +570,7 @@ def make_sharded_solver(
     # check_vma=False: the Pallas interpret path (CPU tests/dryruns) does
     # not yet propagate varying-mesh-axes through in-kernel concatenates;
     # parity with the roll kernel is pinned by tests instead.
-    sharded_fn = compat.shard_map(
+    sharded_fn = jax.shard_map(
         local_solve,
         mesh=mesh,
         in_specs=tuple(in_specs),
@@ -652,7 +651,7 @@ def make_sharded_resumer(
     out_specs = [state_spec, state_spec, P(), P()]
     if compensated:
         out_specs += [state_spec, state_spec]
-    sharded_fn = compat.shard_map(
+    sharded_fn = jax.shard_map(
         local_resume,
         mesh=mesh,
         in_specs=tuple(in_specs),
@@ -733,7 +732,7 @@ def make_sharded_chunk_runner(
     out_specs = [state_spec, state_spec, P(), P()]
     if compensated:
         out_specs += [state_spec, state_spec]
-    sharded_fn = compat.shard_map(
+    sharded_fn = jax.shard_map(
         local_chunk,
         mesh=mesh,
         in_specs=tuple(in_specs),
@@ -769,8 +768,7 @@ def _run_timed(runner, rt_args):
     out = compiled(*rt_args)
     jax.block_until_ready(out)
     # The small error-vector readback inside the timed region proves the
-    # program actually ran: on remote backends block_until_ready can return
-    # before execution (see leapfrog._timed_compile_run).
+    # program actually ran (see leapfrog._timed_compile_run).
     abs_np = np.asarray(out[2], dtype=np.float64)
     rel_np = np.asarray(out[3], dtype=np.float64)
     t2 = time.perf_counter()

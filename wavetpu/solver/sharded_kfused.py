@@ -48,7 +48,6 @@ from jax.sharding import PartitionSpec as P
 
 from wavetpu.core.grid import build_mesh
 from wavetpu.core.problem import Problem
-from wavetpu import compat
 from wavetpu.kernels import stencil_pallas, stencil_ref
 from wavetpu.obs import metrics as obs_metrics
 from wavetpu.solver import kfused, leapfrog
@@ -308,7 +307,7 @@ def _make_runner(
                 fargs[0] if has_field else None,
             )
 
-        local_fn = compat.shard_map(
+        local_fn = jax.shard_map(
             local_chunk, mesh=mesh,
             in_specs=(state_spec, state_spec, P(), rows_spec, plane_spec,
                       plane_spec) + field_specs,
@@ -358,7 +357,7 @@ def _make_runner(
                 jnp.concatenate([zero, r1, rows_r]),
             )
 
-        local_fn = compat.shard_map(
+        local_fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(state_spec, rows_spec, plane_spec, plane_spec)
             + field_specs,
@@ -396,7 +395,7 @@ def _make_runner(
             jnp.concatenate([head, rows_r]),
         )
 
-    local_fn = compat.shard_map(
+    local_fn = jax.shard_map(
         local_resume, mesh=mesh,
         in_specs=(state_spec, state_spec, rows_spec, plane_spec,
                   plane_spec) + field_specs,
@@ -630,7 +629,7 @@ def _make_padded_runner(
                 fargs[0] if has_field else None,
             )
 
-        local_fn = compat.shard_map(
+        local_fn = jax.shard_map(
             local_chunk, mesh=mesh,
             in_specs=(state_spec, state_spec, P(), rows_spec, plane_spec,
                       plane_spec) + field_specs,
@@ -676,7 +675,7 @@ def _make_padded_runner(
                 jnp.concatenate([zero, r1, rows_r]),
             )
 
-        local_fn = compat.shard_map(
+        local_fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(state_spec, rows_spec, plane_spec, plane_spec)
             + field_specs,
@@ -712,7 +711,7 @@ def _make_padded_runner(
             jnp.concatenate([head, rows_r]),
         )
 
-    local_fn = compat.shard_map(
+    local_fn = jax.shard_map(
         local_resume, mesh=mesh,
         in_specs=(state_spec, state_spec, rows_spec, plane_spec,
                   plane_spec) + field_specs,

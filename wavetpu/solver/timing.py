@@ -46,7 +46,6 @@ from jax.sharding import PartitionSpec as P
 
 from wavetpu.core.grid import AXIS_NAMES, Topology, build_mesh, choose_mesh_shape
 from wavetpu.core.problem import Problem
-from wavetpu import compat
 from wavetpu.kernels import stencil_ref
 from wavetpu.solver import sharded as _sharded
 
@@ -83,14 +82,13 @@ def _probe_runner(problem: Problem, topo: Topology, mesh, dtype, kernel,
         (u_prev, u), _ = jax.lax.scan(
             body, (u_prev + salt, u), None, length=iters
         )
-        # Scalar checksum output: reading it back on the host both forces
-        # execution (remote backends can defer past block_until_ready) and
-        # keeps the transfer tiny.
+        # Scalar checksum output: reading it back on the host forces
+        # execution and keeps the transfer tiny.
         return jax.lax.psum(jnp.sum(u), AXIS_NAMES)
 
     spec = P(*AXIS_NAMES)
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(spec, spec, P("x"), P("y"), P("z"), P()),
@@ -103,8 +101,8 @@ def _probe_runner(problem: Problem, topo: Topology, mesh, dtype, kernel,
 def _time_best(fn, args, repeats: int) -> float:
     """Best-of-N wall time of the compiled callable (compile excluded).
 
-    Each call gets a distinct `salt` input so remote backends cannot serve
-    a memoized result, and the scalar output is read back to force
+    Each call gets a distinct `salt` input so no call can be served a
+    memoized result, and the scalar output is read back to force
     completion.
     """
     np.asarray(fn(*args, jnp.zeros((), args[0].dtype)))  # compile + warm up
@@ -186,7 +184,7 @@ def _kfused_probe_runner(problem, grid, mesh, dtype, k, interpret,
     state_spec = P("x", "y")
     plane_spec = P("y", None)
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(state_spec, state_spec, plane_spec, plane_spec,
@@ -265,7 +263,7 @@ def _kfused_comp_probe_runner(problem, grid, mesh, dtype, v_dtype,
     state_spec = P("x", "y")
     plane_spec = P("y", None)
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(state_spec, state_spec,
