@@ -221,8 +221,14 @@ class TestRegistry:
 
 class TestTracing:
     def test_disabled_tracer_is_noop(self):
+        """Untraced, nothing is recorded: the handle holds only the
+        profiler annotation (no span id), and None / a second end are
+        no-ops."""
         tracing.disable()
-        assert tracing.begin_span("x") is None
+        h = tracing.begin_span("x")
+        assert set(h) == {"_annotation"} and not tracing.enabled()
+        tracing.end_span(h)
+        tracing.end_span(h)
         tracing.end_span(None)
         tracing.event("x", a=1)  # no crash, nothing written
         with tracing.span("x", a=1) as attrs:
@@ -886,3 +892,216 @@ class TestSupervisedTelemetry:
         assert span["attrs"]["aborted"] is True
         # the final heartbeat landed too
         assert (tel / "heartbeat.jsonl").exists()
+
+
+# ---- program spans in the profiler's trace ----
+
+
+class _Annotations:
+    """Stands in for jax.profiler.TraceAnnotation: records each enter
+    and exit by name, so a test can see spans open and close."""
+
+    def __init__(self):
+        self.events = []
+        rec = self.events
+
+        class Annotation:
+            def __init__(self, name, **kwargs):
+                self.name = name
+
+            def __enter__(self):
+                rec.append(("enter", self.name))
+                return self
+
+            def __exit__(self, *exc):
+                rec.append(("exit", self.name))
+
+        self.cls = Annotation
+
+    def balanced(self):
+        opened = [n for e, n in self.events if e == "enter"]
+        closed = [n for e, n in self.events if e == "exit"]
+        return sorted(opened) == sorted(closed)
+
+    def kinds(self):
+        return {n for e, n in self.events if e == "enter"}
+
+
+@pytest.fixture()
+def annotations(monkeypatch):
+    import jax
+
+    a = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", a.cls)
+    return a
+
+
+class TestProgramSpans:
+    """Every span is a profiler annotation whether or not a tracer is
+    configured; the JSONL record is written only under a tracer."""
+
+    def test_untraced_spans_open_one_annotation(self, annotations,
+                                                tmp_path, monkeypatch):
+        tracing.disable()
+        monkeypatch.chdir(tmp_path)
+        with tracing.span("a.span", x=1) as attrs:
+            attrs["y"] = 2
+        assert annotations.events == [("enter", "a.span"),
+                                      ("exit", "a.span")]
+        h = tracing.begin_span("b.span", remote=("1" * 32, None))
+        assert annotations.events[-1] == ("enter", "b.span")
+        tracing.end_span(h, status=200)
+        tracing.end_span(h, status=500)  # a second end closes nothing
+        assert annotations.events[-2:] == [("enter", "b.span"),
+                                           ("exit", "b.span")]
+        assert os.listdir(tmp_path) == []  # no file written
+
+    def test_annotation_closes_on_exception(self, annotations):
+        tracing.disable()
+        with pytest.raises(RuntimeError):
+            with tracing.span("boom.span"):
+                raise RuntimeError("inside")
+        assert annotations.events == [("enter", "boom.span"),
+                                      ("exit", "boom.span")]
+
+    def test_traced_span_is_one_annotation_and_one_record(
+            self, annotations, tmp_path):
+        path = str(tmp_path / "trace.jsonl")
+        tracing.configure(path)
+        try:
+            with tracing.span("c.span"):
+                h = tracing.begin_span("d.span")
+                tracing.end_span(h, ok=True)
+        finally:
+            tracing.disable()
+        assert annotations.events == [
+            ("enter", "c.span"), ("enter", "d.span"),
+            ("exit", "d.span"), ("exit", "c.span")]
+        assert [json.loads(line)["kind"] for line in open(path)] == [
+            "d.span", "c.span"]
+
+    def test_tracer_configured_mid_span_still_closes(self, annotations,
+                                                     tmp_path):
+        """A light handle begun untraced is closed, not recorded, by an
+        end that finds a tracer configured since (and the reverse)."""
+        tracing.disable()
+        h = tracing.begin_span("e.span")
+        path = str(tmp_path / "trace.jsonl")
+        tracing.configure(path)
+        try:
+            tracing.end_span(h)
+            h2 = tracing.begin_span("f.span")
+        finally:
+            tracing.disable()
+        tracing.end_span(h2)
+        assert annotations.balanced()
+        assert open(path).read() == ""
+
+    def test_untraced_cli_solve_keeps_its_behaviour(self, annotations,
+                                                    tmp_path):
+        """The solo CLI untraced: no trace file, no telemetry thread,
+        the solve's program spans open and close as annotations, and
+        the traced-only roofline lookup (`tracing.enabled()`) is
+        skipped."""
+        from wavetpu.cli import main
+
+        tracing.disable()
+        before = set(threading.enumerate())
+        rc = main(["8", "1", "1", "1", "1", "1", "4", "--backend",
+                   "single", "--kernel", "roll", "--out-dir",
+                   str(tmp_path)])
+        assert rc == 0
+        assert annotations.balanced()
+        assert {"cli.solve", "solve.prepare", "solve.run",
+                "solve.finish"} <= annotations.kinds()
+        assert not any(p.endswith(".jsonl") for p in os.listdir(tmp_path))
+        assert set(threading.enumerate()) <= before  # no thread left
+
+
+def _host_spans(reduced, kind):
+    return sorted((s for s in reduced.spans if s.name.split("#")[0] == kind),
+                  key=lambda s: s.start)
+
+
+class TestProfilerCapture:
+    """A CPU profiler capture of one solo solve and one served request,
+    with no tracer configured, holds the program's spans on its host
+    plane, read back by the benchmark's own trace reduction."""
+
+    def test_spans_reach_the_profile(self, tmp_path):
+        import sys
+        import urllib.request
+
+        import jax
+        from jax.profiler import ProfileData
+
+        from wavetpu.core.problem import Problem
+        from wavetpu.serve.api import build_server
+        from wavetpu.solver import kfused_comp
+
+        sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                        "benchmark"))
+        import tracereduce
+
+        tracing.disable()
+        httpd, state = build_server(port=0, max_wait=0.01,
+                                    default_kernel="roll", interpret=True)
+        thread = threading.Thread(target=httpd.serve_forever, daemon=True)
+        thread.start()
+        base = f"http://127.0.0.1:{httpd.server_address[1]}"
+        problem = Problem(N=8, Np=1, Lx=1.0, Ly=1.0, Lz=1.0, T=1.0,
+                          timesteps=9)
+        body = json.dumps({"N": 8, "timesteps": 4}).encode()
+
+        def solve_and_serve():
+            with jax.profiler.TraceAnnotation("bench.solve"):
+                kfused_comp.solve_kfused_comp(problem, k=4, interpret=True)
+            req = urllib.request.Request(
+                base + "/solve", data=body,
+                headers={"Content-Type": "application/json"})
+            with urllib.request.urlopen(req, timeout=300) as r:
+                assert r.status == 200
+
+        out = str(tmp_path / "prof")
+        try:
+            # Warm: the first call compiles the entry's eager constants
+            # while it builds its runner, before `solve.prepare` opens.
+            solve_and_serve()
+            jax.profiler.start_trace(out)
+            try:
+                with jax.profiler.TraceAnnotation("bench.window"):
+                    solve_and_serve()
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            httpd.shutdown()
+            state.batcher.close()
+            httpd.server_close()
+        assert not os.path.exists(tmp_path / "trace.jsonl")
+        red = tracereduce.reduce_profile(
+            ProfileData.from_file(tracereduce.xplane_path(out)))
+
+        # solo: prepare, run, finish, once each, tiling the call
+        (call,) = _host_spans(red, "bench.solve")
+        (prep,) = _host_spans(red, "solve.prepare")
+        (run,) = _host_spans(red, "solve.run")
+        (fin,) = _host_spans(red, "solve.finish")
+        assert call.start <= prep.start < prep.end <= run.start
+        assert run.start < run.end <= fin.start < fin.end <= call.end
+        gaps = (run.start - prep.end) + (fin.start - run.end)
+        assert gaps < 0.05
+        covered = (prep.end - prep.start) + (run.end - run.start) \
+            + (fin.end - fin.start)
+        assert covered >= 0.9 * (call.end - call.start)
+
+        # serve: request > batch > execute > pack, run, results
+        (request,) = _host_spans(red, "serve.request")
+        (batch,) = _host_spans(red, "serve.batch")
+        (execute,) = _host_spans(red, "serve.execute")
+        assert request.start <= batch.start < batch.end <= request.end
+        assert batch.start <= execute.start < execute.end <= batch.end
+        last = execute.start
+        for kind in ("ensemble.pack", "ensemble.run", "ensemble.results"):
+            (s,) = _host_spans(red, kind)
+            assert last <= s.start < s.end <= execute.end, kind
+            last = s.end

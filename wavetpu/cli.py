@@ -780,9 +780,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         # so persistence here is JAX's own compilation cache.  The hit
         # counter marks the ledger entry `source: disk` when the cache
         # actually served this solve's compile.
-        from wavetpu.serve import progcache as _progcache
-
-        xla_cache_hits = _progcache.shared_xla_hit_counter()
+        xla_cache_hits = jaxcache.shared_xla_hit_counter()
     solve_span = _tracing.begin_span(
         "cli.solve", backend=backend, scheme=scheme, kernel=kernel,
         fuse_steps=fuse_steps, n=problem.N,
@@ -1224,7 +1222,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         # the same path label the solver used.  Traced runs only -
         # untraced runs skip even the lookup.
         span_extra = {}
-        if solve_span is not None:
+        if _tracing.enabled():
             try:
                 from wavetpu.obs.registry import get_registry as _greg
 

@@ -49,6 +49,7 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from wavetpu.core.problem import Problem
+from wavetpu.obs import tracing
 from wavetpu.verify import oracle
 
 PATHS = ("roll", "pallas", "kfused")
@@ -866,43 +867,55 @@ class EnsembleSolver:
         """Execute the batch; returns (outputs, init_seconds,
         solve_seconds) with outputs = (u_prev_b, u_cur_b, abs_b, rel_b).
         init_seconds is the compile time this call paid (0 when warm)."""
-        import jax
+        return run_batch(self, lanes)
 
-        init_s = self.compile()
-        args = self.pack(lanes)
+
+def run_batch(solver, lanes: Sequence[LaneSpec]):
+    """`solver.run` of the vmapped and the sharded batched solvers: the
+    host's lane packing (span `ensemble.pack`), then the execute through
+    `block_until_ready` and the error-block readback (`ensemble.run`,
+    timed as solve_seconds)."""
+    import jax
+
+    init_s = solver.compile()
+    with tracing.span("ensemble.pack"):
+        args = solver.pack(lanes)
+    with tracing.span("ensemble.run"):
         t0 = time.perf_counter()
-        out = self._exec(*args)
+        out = solver._exec(*args)
         jax.block_until_ready(out)
         # Readback proves execution (the same reasoning as
         # leapfrog._timed_compile_run's sync): the (B, T+1) error block
         # is the smallest always-present output.
         np.asarray(out[2])
         solve_s = time.perf_counter() - t0
-        return out, init_s, solve_s
+    return out, init_s, solve_s
 
 
 def _lane_results(problem, outputs, lanes, init_s, solve_s):
     """Per-lane SolveResults from batched outputs (padding already
-    dropped by the caller passing only real lanes and their indices)."""
+    dropped by the caller passing only real lanes and their indices),
+    in the span `ensemble.results`."""
     from wavetpu.solver.leapfrog import SolveResult
 
     upb, ucb, ab, rb = outputs
     results = []
-    for i, lane in enumerate(lanes):
-        s = lane.stop(problem)
-        results.append(
-            SolveResult(
-                problem=problem,
-                u_prev=upb[i],
-                u_cur=ucb[i],
-                abs_errors=np.asarray(ab[i], np.float64)[: s + 1],
-                rel_errors=np.asarray(rb[i], np.float64)[: s + 1],
-                init_seconds=init_s,
-                solve_seconds=solve_s,
-                steps_computed=s,
-                final_step=s,
+    with tracing.span("ensemble.results"):
+        for i, lane in enumerate(lanes):
+            s = lane.stop(problem)
+            results.append(
+                SolveResult(
+                    problem=problem,
+                    u_prev=upb[i],
+                    u_cur=ucb[i],
+                    abs_errors=np.asarray(ab[i], np.float64)[: s + 1],
+                    rel_errors=np.asarray(rb[i], np.float64)[: s + 1],
+                    init_seconds=init_s,
+                    solve_seconds=solve_s,
+                    steps_computed=s,
+                    final_step=s,
+                )
             )
-        )
     return results
 
 

@@ -43,6 +43,7 @@ from wavetpu.ensemble.batched import (
     LaneSpec,
     _lane_results,
     padding_lane,
+    run_batch,
 )
 from wavetpu.verify import oracle
 
@@ -284,16 +285,7 @@ class ShardedEnsembleSolver:
         return self.compile_seconds
 
     def run(self, lanes: Sequence[LaneSpec]):
-        import jax
-
-        init_s = self.compile()
-        args = self.pack(lanes)
-        t0 = time.perf_counter()
-        out = self._exec(*args)
-        jax.block_until_ready(out)
-        np.asarray(out[2])  # readback proves execution (leapfrog sync)
-        solve_s = time.perf_counter() - t0
-        return out, init_s, solve_s
+        return run_batch(self, lanes)
 
 
 # ---- capability probe ----

@@ -17,6 +17,7 @@ later `configure()` resets that decision.
 from __future__ import annotations
 
 import os
+import threading
 from typing import Optional
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -39,3 +40,44 @@ def configure() -> Optional[str]:
         jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
         compilation_cache.reset_cache()
     return DEFAULT_DIR
+
+
+class XlaCacheHitCounter:
+    """Counts `/jax/compilation_cache/cache_hits` monitoring events -
+    the only signal the in-process XLA cache exposes.  Lets the solo
+    CLI (and the fallback serve tier) mark its ledger entry
+    `source: disk` when the persistent cache actually served the
+    compile, and the solvers' `solve.prepare` span say whether XLA
+    compiled.  Best-effort: an older jax without the monitoring hook
+    just never counts."""
+
+    def __init__(self):
+        self.hits = 0
+        self.installed = False
+        try:
+            from jax._src import monitoring
+
+            def _cb(name, **kw):
+                if "compilation_cache/cache_hits" in name:
+                    self.hits += 1
+
+            monitoring.register_event_listener(_cb)
+            self._cb = _cb
+            self.installed = True
+        except Exception:
+            pass
+
+
+_XLA_HITS: Optional[XlaCacheHitCounter] = None
+_hits_lock = threading.Lock()
+
+
+def shared_xla_hit_counter() -> XlaCacheHitCounter:
+    """One process-wide counter (the monitoring listener cannot be
+    unregistered, so per-instance counters would pile up a callback per
+    ProgramCache a test suite creates)."""
+    global _XLA_HITS
+    with _hits_lock:
+        if _XLA_HITS is None:
+            _XLA_HITS = XlaCacheHitCounter()
+        return _XLA_HITS

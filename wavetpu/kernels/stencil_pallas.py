@@ -212,6 +212,7 @@ def _fused_step(u_prev, u, *, inv_h2, alpha=2.0, beta=1.0, coeff=None,
         out_specs=slab,
         out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        name="fused_step",
         interpret=interpret,
     )(*operands)
 
@@ -497,6 +498,7 @@ def sharded_fused_step(u_prev, u, ghosts, offsets, n_global, *, inv_h2,
         out_specs=slab,
         out_shape=_out_struct(u),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        name="sharded_fused_step",
         interpret=interpret,
     )(*operands)
 
@@ -536,6 +538,7 @@ def sharded_compensated_step(u, v, carry, ghosts, offsets, n_global, *,
         out_specs=[slab, slab, slab],
         out_shape=[out, out, out],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        name="sharded_compensated_step",
         interpret=interpret,
     )(*operands)
 
@@ -598,6 +601,7 @@ def compensated_step(u, v, carry, problem: Problem, coeff=None, *,
         out_specs=[slab, slab, slab],
         out_shape=[out, out, out],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        name="compensated_step",
         interpret=interpret,
     )(v, carry, u, u, u)
 
@@ -896,6 +900,7 @@ def fused_kstep(u_prev, u, syz, rsyz, sxct, *, k, coeff, inv_h2,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_KSTEP_VMEM_LIMIT
         ),
+        name="fused_kstep",
         interpret=interpret,
     )(*operands)
     if with_errors:
@@ -1150,6 +1155,7 @@ def fused_kstep_comp(u, v, carry, syz, rsyz, sxct, *, k, coeff, inv_h2,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_KSTEP_COMP_VMEM_LIMIT
         ),
+        name="fused_kstep_comp",
         interpret=interpret,
     )(*operands)
     u_o, v_o = out[0], out[1]
@@ -1356,6 +1362,7 @@ def fused_kstep_comp_sharded(u, v, carry, u_ghosts, v_ghosts, syz, rsyz,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_KSTEP_COMP_VMEM_LIMIT
         ),
+        name="fused_kstep_comp_sharded",
         interpret=interpret,
     )(*operands)
     u_o, v_o = out[0], out[1]
@@ -1580,6 +1587,7 @@ def fused_kstep_comp_sharded_xy(u_ext, v_ext, carry, u_ghosts, v_ghosts,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_KSTEP_COMP_VMEM_LIMIT
         ),
+        name="fused_kstep_comp_sharded_xy",
         interpret=interpret,
     )(*operands)
     u_o, v_o = out[0], out[1]
@@ -1771,6 +1779,7 @@ def fused_kstep_sharded(u_prev, u, prev_ghosts, cur_ghosts, syz, rsyz, sxct,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_KSTEP_VMEM_LIMIT
         ),
+        name="fused_kstep_sharded",
         interpret=interpret,
     )(*operands)
     if with_errors:
@@ -1961,6 +1970,7 @@ def fused_kstep_padded(ext_prev, ext_cur, n_real, syz, rsyz, sxct, *,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_KSTEP_VMEM_LIMIT
         ),
+        name="fused_kstep_padded",
         interpret=interpret,
     )(*operands)
     if with_errors:
@@ -2155,6 +2165,7 @@ def fused_kstep_sharded_xy(u_prev_ext, u_ext, prev_ghosts, cur_ghosts,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_KSTEP_VMEM_LIMIT
         ),
+        name="fused_kstep_sharded_xy",
         interpret=interpret,
     )(*operands)
     if with_errors:

@@ -14,22 +14,29 @@ tail and `wavetpu trace-report` can summarize:
    the enclosing span on the SAME THREAD as parent, measures wall time,
    and writes one record on exit.  The yielded dict is the record's
    `attrs`: mutate it to attach results discovered mid-span (occupancy,
-   cache verdicts).  While a span is open it also holds a matching
-   `jax.profiler.TraceAnnotation(kind)` - IF jax is already imported -
-   so application spans line up with device traces captured via
-   `--profile` in the same run.  (jax is never imported here: tracing
-   must not drag the backend in; `sys.modules` is consulted instead.)
+   cache verdicts).
  * `begin_span()` / `end_span()` - the same span without the `with`
    block, for call sites where a context manager would force a 300-line
    reindent (cli.py's solve dispatch).
  * `event(kind, **attrs)` - a zero-duration record.
 
-The module-level tracer is a process-wide singleton configured by
-`configure(path)` (the CLI's `--telemetry-dir` does this).  When NOT
-configured every call is a cheap no-op - `span()` yields a throwaway
-dict without allocating ids or touching any lock - so instrumented code
-paths cost nothing in untraced runs (bench.py pins the traced overhead
-itself at <= 2%).
+Every span, configured tracer or not, holds a matching
+`jax.profiler.TraceAnnotation(kind)` while it is open - IF jax is
+already imported - so whenever the profiler runs (`--profile`,
+`wavetpu profile`, the benchmark's traced runs) the program's spans
+land on the host plane of the device trace, on the profiler's own
+clock, named by their bare `kind`.  With the profiler off an
+annotation costs one small object.  (jax is never imported here:
+tracing must not drag the backend in; `sys.modules` is consulted
+instead.)
+
+The JSONL records come from the module-level tracer, a process-wide
+singleton configured by `configure(path)` (the CLI's `--telemetry-dir`
+does this).  When NOT configured no record is written and no id is
+allocated - `span()` yields a throwaway dict and `begin_span()` returns
+a light handle holding only the annotation - so instrumented code paths
+cost one annotation in untraced runs.  Code that must know whether
+records are being written asks `enabled()`, never the handle.
 
 Cross-thread linkage: parenthood is thread-local (a scheduler-worker
 span is not a child of whatever the HTTP thread had open).  Cross-thread
@@ -139,6 +146,28 @@ def rotate_file(path: str, keep: int) -> None:
             os.replace(src, f"{path}.{i}")
 
 
+def _open_annotation(kind: str):
+    """Enter a `jax.profiler.TraceAnnotation(kind)` when jax is loaded;
+    returns it (None without jax, or if the profiler refuses)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        annotation = jax.profiler.TraceAnnotation(kind)
+        annotation.__enter__()
+    except Exception:
+        return None
+    return annotation
+
+
+def _close_annotation(annotation) -> None:
+    if annotation is not None:
+        try:
+            annotation.__exit__(None, None, None)
+        except Exception:
+            pass
+
+
 _tracer_instances = itertools.count()
 
 
@@ -236,14 +265,7 @@ class Tracer:
         belongs to a request's trace but is not its tree child).
         `links` attaches record-level cross-trace links (the preemption
         resume chain)."""
-        annotation = None
-        jax = sys.modules.get("jax")
-        if jax is not None:
-            try:
-                annotation = jax.profiler.TraceAnnotation(kind)
-                annotation.__enter__()
-            except Exception:
-                annotation = None
+        annotation = _open_annotation(kind)
         if remote is not None:
             parent_id: Optional[str] = remote[1]
             trace_id = remote[0]
@@ -281,12 +303,7 @@ class Tracer:
                 if sid == handle["span_id"]:
                     del st[i]
                     break
-        annotation = handle.pop("_annotation", None)
-        if annotation is not None:
-            try:
-                annotation.__exit__(None, None, None)
-            except Exception:
-                pass
+        _close_annotation(handle.pop("_annotation", None))
         handle["attrs"] = dict(handle["attrs"], **extra_attrs)
         dur = time.perf_counter() - t0
         record = {
@@ -378,11 +395,16 @@ def enabled() -> bool:
 def span(kind: str, /, remote: Optional[Tuple[str, Optional[str]]] = None,
          links: Optional[List[dict]] = None,
          trace_id: Optional[str] = None, **attrs):
-    """Module-level span: no-op (fresh throwaway attrs dict) when no
-    tracer is configured, so instrumented paths cost nothing untraced."""
+    """Module-level span.  With no tracer configured it writes nothing
+    and yields a fresh throwaway attrs dict; the profiler annotation is
+    open either way."""
     t = _tracer
     if t is None:
-        yield attrs
+        annotation = _open_annotation(kind)
+        try:
+            yield attrs
+        finally:
+            _close_annotation(annotation)
         return
     with t.span(kind, remote=remote, links=links, trace_id=trace_id,
                 **attrs) as a:
@@ -392,18 +414,29 @@ def span(kind: str, /, remote: Optional[Tuple[str, Optional[str]]] = None,
 def begin_span(kind: str, /,
                remote: Optional[Tuple[str, Optional[str]]] = None,
                links: Optional[List[dict]] = None,
-               trace_id: Optional[str] = None, **attrs
-               ) -> Optional[dict]:
+               trace_id: Optional[str] = None, **attrs) -> dict:
+    """Open a span; pass the handle to `end_span` on the same thread.
+    With no tracer configured the handle holds only the profiler
+    annotation (ask `enabled()`, not the handle, whether records are
+    being written)."""
     t = _tracer
-    return None if t is None else t.begin(
-        kind, attrs, remote=remote, links=links, trace_id=trace_id
-    )
+    if t is None:
+        return {"_annotation": _open_annotation(kind)}
+    return t.begin(kind, attrs, remote=remote, links=links,
+                   trace_id=trace_id)
 
 
 def end_span(handle: Optional[dict], **extra_attrs) -> None:
+    """Close a `begin_span` handle (None and a second end are no-ops).
+    A record is written when the span was opened with a tracer and one
+    is still configured; the annotation is closed in every case."""
+    if handle is None:
+        return
     t = _tracer
-    if t is not None and handle is not None:
+    if t is not None and "span_id" in handle:
         t.end(handle, **extra_attrs)
+    else:
+        _close_annotation(handle.pop("_annotation", None))
 
 
 def event(kind: str, /, **attrs) -> None:

@@ -626,7 +626,6 @@ class _Handler(BaseHTTPRequestHandler):
         inbound_tp = self.headers.get("traceparent")
         ctx = tracing.parse_traceparent(inbound_tp)
         echo_tp = inbound_tp if ctx else None
-        span = None
         self._trace_context: Optional[Tuple[str, str]] = None
         if tracing.enabled():
             trace_id = ctx[0] if ctx else tracing.mint_trace_id()
@@ -638,6 +637,10 @@ class _Handler(BaseHTTPRequestHandler):
                 remote=(trace_id, ctx[1] if ctx else None),
                 request_id=rid, w3c_id=w3c,
             )
+        else:
+            # Untraced: no record, but the profiler annotation still
+            # marks the request on the device trace's timeline.
+            span = tracing.begin_span("serve.request")
         code = None
         headers: dict = {}
         # Per-tenant in-flight accounting: _handle_solve records the

@@ -197,48 +197,6 @@ def probe_results() -> list:
     }]
 
 
-# --------------------------------------- XLA persistent-cache hits
-
-
-class XlaCacheHitCounter:
-    """Counts `/jax/compilation_cache/cache_hits` monitoring events -
-    the only signal the in-process XLA cache exposes.  Lets the solo
-    CLI (and the fallback serve tier) mark its ledger entry
-    `source: disk` when the persistent cache actually served the
-    compile.  Best-effort: an older jax without the monitoring hook
-    just never counts."""
-
-    def __init__(self):
-        self.hits = 0
-        self.installed = False
-        try:
-            from jax._src import monitoring
-
-            def _cb(name, **kw):
-                if "compilation_cache/cache_hits" in name:
-                    self.hits += 1
-
-            monitoring.register_event_listener(_cb)
-            self._cb = _cb
-            self.installed = True
-        except Exception:
-            pass
-
-
-_XLA_HITS: Optional[XlaCacheHitCounter] = None
-
-
-def shared_xla_hit_counter() -> XlaCacheHitCounter:
-    """One process-wide counter (the monitoring listener cannot be
-    unregistered, so per-instance counters would pile up a callback per
-    ProgramCache a test suite creates)."""
-    global _XLA_HITS
-    with _probe_lock:
-        if _XLA_HITS is None:
-            _XLA_HITS = XlaCacheHitCounter()
-        return _XLA_HITS
-
-
 # ------------------------------------------------------ the disk tier
 
 
@@ -289,8 +247,8 @@ class ProgramCache:
         # compile), and - in AOT mode - the store guard: a payload
         # serialized from a cache-served executable fails to
         # deserialize, so such compiles must never be put().
-        self.xla_hits: Optional[XlaCacheHitCounter] = (
-            shared_xla_hit_counter() if self.xla_cache else None
+        self.xla_hits: Optional[jaxcache.XlaCacheHitCounter] = (
+            jaxcache.shared_xla_hit_counter() if self.xla_cache else None
         )
         self.fingerprint = env_fingerprint()
         self._fp_hash = hashlib.sha256(

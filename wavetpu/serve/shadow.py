@@ -177,13 +177,12 @@ class ShadowSampler:
     def _run(self, request, lane_result, request_id, trace_context):
         span = None
         try:
-            if tracing.enabled():
-                span = tracing.begin_span(
-                    "serve.shadow", remote=trace_context,
-                    request_id=request_id,
-                    scheme=request.scheme, path=request.path,
-                    k=request.k, dtype=request.dtype_name,
-                )
+            span = tracing.begin_span(
+                "serve.shadow", remote=trace_context,
+                request_id=request_id,
+                scheme=request.scheme, path=request.path,
+                k=request.k, dtype=request.dtype_name,
+            )
             # Chaos seam: the shadow worker dies before the twin runs.
             # Fired HERE - outside the engine - so the drill also
             # proves the breaker never hears a shadow crash.

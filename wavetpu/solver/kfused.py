@@ -50,6 +50,7 @@ from jax import lax
 from wavetpu.core.problem import Problem
 from wavetpu.kernels import stencil_pallas, stencil_ref
 from wavetpu.obs import metrics as obs_metrics
+from wavetpu.obs import tracing
 from wavetpu.solver import leapfrog
 from wavetpu.verify import oracle
 
@@ -307,24 +308,27 @@ def solve_kfused(
     )
     (u_prev, u_cur, abs_all, rel_all), init_s, solve_s = (
         leapfrog._timed_compile_run(
-            runner, run_params, sync=lambda out: np.asarray(out[2])
+            runner, run_params, sync=lambda out: np.asarray(out[2]),
+            path="kfused", scheme="standard", k=k, n=problem.N,
         )
     )
-    result = leapfrog.SolveResult(
-        problem=problem,
-        u_prev=u_prev,
-        u_cur=u_cur,
-        abs_errors=np.asarray(abs_all, dtype=np.float64),
-        rel_errors=np.asarray(rel_all, dtype=np.float64),
-        init_seconds=init_s,
-        solve_seconds=solve_s,
-        steps_computed=stop_step,
-        final_step=stop_step if stop_step is not None else problem.timesteps,
-    )
-    obs_metrics.record_solve(
-        result, "kfused", k=k, with_field=c2tau2_field is not None,
-        block_x=block_x,
-    )
+    with tracing.span("solve.finish", path="kfused"):
+        result = leapfrog.SolveResult(
+            problem=problem,
+            u_prev=u_prev,
+            u_cur=u_cur,
+            abs_errors=np.asarray(abs_all, dtype=np.float64),
+            rel_errors=np.asarray(rel_all, dtype=np.float64),
+            init_seconds=init_s,
+            solve_seconds=solve_s,
+            steps_computed=stop_step,
+            final_step=(stop_step if stop_step is not None
+                        else problem.timesteps),
+        )
+        obs_metrics.record_solve(
+            result, "kfused", k=k, with_field=c2tau2_field is not None,
+            block_x=block_x,
+        )
     return result
 
 
@@ -384,7 +388,8 @@ def resume_kfused(
         args = args + (field_dev,)
     (u_p, u_c, abs_all, rel_all), init_s, solve_s = (
         leapfrog._timed_compile_run(
-            jax.jit(run), args, sync=lambda out: np.asarray(out[2])
+            jax.jit(run), args, sync=lambda out: np.asarray(out[2]),
+            path="kfused", scheme="standard", k=k, n=problem.N,
         )
     )
     return leapfrog.SolveResult(

@@ -59,6 +59,7 @@ from jax import lax
 from wavetpu.core.problem import Problem
 from wavetpu.kernels import stencil_pallas, stencil_ref
 from wavetpu.obs import metrics as obs_metrics
+from wavetpu.obs import tracing
 from wavetpu.solver import kfused, leapfrog
 from wavetpu.verify import oracle
 
@@ -417,20 +418,22 @@ def solve_kfused_comp(
         v_dtype, carry, carry_dtype, c2tau2_field, phase,
     )
     out, init_s, solve_s = leapfrog._timed_compile_run(
-        runner, run_params, sync=lambda o: np.asarray(o[3])
+        runner, run_params, sync=lambda o: np.asarray(o[3]),
+        path="kfused_comp", scheme="compensated", k=k, n=problem.N,
     )
-    result = _as_result(
-        problem, out, init_s, solve_s, stop_step,
-        stop_step if stop_step is not None else problem.timesteps,
-    )
-    obs_metrics.record_solve(
-        result, "kfused_comp", scheme="compensated", k=k,
-        v_itemsize=(
-            None if v_dtype is None else jnp.dtype(v_dtype).itemsize
-        ),
-        carry=carry, with_field=c2tau2_field is not None,
-        block_x=block_x,
-    )
+    with tracing.span("solve.finish", path="kfused_comp"):
+        result = _as_result(
+            problem, out, init_s, solve_s, stop_step,
+            stop_step if stop_step is not None else problem.timesteps,
+        )
+        obs_metrics.record_solve(
+            result, "kfused_comp", scheme="compensated", k=k,
+            v_itemsize=(
+                None if v_dtype is None else jnp.dtype(v_dtype).itemsize
+            ),
+            carry=carry, with_field=c2tau2_field is not None,
+            block_x=block_x,
+        )
     return result
 
 
@@ -825,23 +828,25 @@ def solve_kfused_comp_sharded(
             NamedSharding(mesh, P("x", "y")),
         ),)
     out, init_s, solve_s = leapfrog._timed_compile_run(
-        runner, run_params, sync=lambda o: np.asarray(o[3])
+        runner, run_params, sync=lambda o: np.asarray(o[3]),
+        path="kfused_comp_sharded", scheme="compensated", k=k, n=problem.N,
     )
-    result = _as_result(
-        problem, out, init_s, solve_s, stop_step,
-        stop_step if stop_step is not None else problem.timesteps,
-    )
-    obs_metrics.record_solve(
-        result, "kfused_comp_sharded", scheme="compensated", k=k,
-        v_itemsize=(
-            None if v_dtype is None else jnp.dtype(v_dtype).itemsize
-        ),
-        carry=carry, with_field=c2tau2_field is not None,
-        block_x=block_x,
-        # Same depth/ghosts arguments the sharded chooser above used,
-        # so the roofline model reads the block the kernel runs.
-        depth=problem.N // n_x, ghosts=True,
-    )
+    with tracing.span("solve.finish", path="kfused_comp_sharded"):
+        result = _as_result(
+            problem, out, init_s, solve_s, stop_step,
+            stop_step if stop_step is not None else problem.timesteps,
+        )
+        obs_metrics.record_solve(
+            result, "kfused_comp_sharded", scheme="compensated", k=k,
+            v_itemsize=(
+                None if v_dtype is None else jnp.dtype(v_dtype).itemsize
+            ),
+            carry=carry, with_field=c2tau2_field is not None,
+            block_x=block_x,
+            # Same depth/ghosts arguments the sharded chooser above used,
+            # so the roofline model reads the block the kernel runs.
+            depth=problem.N // n_x, ghosts=True,
+        )
     return result
 
 
@@ -910,7 +915,8 @@ def resume_kfused_comp_sharded(
             jnp.asarray(c2tau2_field, dtype=f), sharding
         ),)
     out, init_s, solve_s = leapfrog._timed_compile_run(
-        runner, args, sync=lambda o: np.asarray(o[3])
+        runner, args, sync=lambda o: np.asarray(o[3]),
+        path="kfused_comp_sharded", scheme="compensated", k=k, n=problem.N,
     )
     return _as_result(
         problem, out, init_s, solve_s, nsteps - start_step, nsteps
@@ -982,7 +988,8 @@ def resume_kfused_comp(
             jnp.asarray(c2tau2_field, dtype=f)
         ),)
     out, init_s, solve_s = leapfrog._timed_compile_run(
-        jax.jit(run), args, sync=lambda o: np.asarray(o[3])
+        jax.jit(run), args, sync=lambda o: np.asarray(o[3]),
+        path="kfused_comp", scheme="compensated", k=k, n=problem.N,
     )
     return _as_result(
         problem, out, init_s, solve_s, nsteps - start_step, nsteps

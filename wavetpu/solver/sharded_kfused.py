@@ -50,6 +50,7 @@ from wavetpu.core.grid import build_mesh
 from wavetpu.core.problem import Problem
 from wavetpu.kernels import stencil_pallas, stencil_ref
 from wavetpu.obs import metrics as obs_metrics
+from wavetpu.obs import tracing
 from wavetpu.solver import kfused, leapfrog
 from wavetpu.solver.leapfrog import SolveResult
 
@@ -823,31 +824,34 @@ def solve_sharded_kfused(
             ),)
     (u_prev, u_cur, abs_all, rel_all), init_s, solve_s = (
         leapfrog._timed_compile_run(
-            runner, run_params, sync=lambda out: np.asarray(out[2])
+            runner, run_params, sync=lambda out: np.asarray(out[2]),
+            path="sharded_kfused", scheme="standard", k=k, n=problem.N,
         )
     )
     if sliced:
         u_prev = _to_topology_layout(u_prev, problem, mesh, n_x)
         u_cur = _to_topology_layout(u_cur, problem, mesh, n_x)
-    result = SolveResult(
-        problem=problem,
-        u_prev=u_prev,
-        u_cur=u_cur,
-        abs_errors=np.asarray(abs_all, dtype=np.float64),
-        rel_errors=np.asarray(rel_all, dtype=np.float64),
-        init_seconds=init_s,
-        solve_seconds=solve_s,
-        steps_computed=stop_step,
-        final_step=stop_step if stop_step is not None else problem.timesteps,
-    )
-    obs_metrics.record_solve(
-        result, "sharded_kfused", k=k,
-        with_field=c2tau2_field is not None, block_x=block_x,
-        # Roofline model: the block is chosen against the SHARD depth
-        # with ghost buffers in the pipeline, same as the kernel's own
-        # chooser call above (ceil covers the pad-and-mask layout).
-        depth=-(-problem.N // n_x), ghosts=True,
-    )
+    with tracing.span("solve.finish", path="sharded_kfused"):
+        result = SolveResult(
+            problem=problem,
+            u_prev=u_prev,
+            u_cur=u_cur,
+            abs_errors=np.asarray(abs_all, dtype=np.float64),
+            rel_errors=np.asarray(rel_all, dtype=np.float64),
+            init_seconds=init_s,
+            solve_seconds=solve_s,
+            steps_computed=stop_step,
+            final_step=(stop_step if stop_step is not None
+                        else problem.timesteps),
+        )
+        obs_metrics.record_solve(
+            result, "sharded_kfused", k=k,
+            with_field=c2tau2_field is not None, block_x=block_x,
+            # Roofline model: the block is chosen against the SHARD depth
+            # with ghost buffers in the pipeline, same as the kernel's own
+            # chooser call above (ceil covers the pad-and-mask layout).
+            depth=-(-problem.N // n_x), ghosts=True,
+        )
     return result
 
 
@@ -927,7 +931,8 @@ def resume_sharded_kfused(
             ),)
     (u_p, u_c, abs_all, rel_all), init_s, solve_s = (
         leapfrog._timed_compile_run(
-            runner, args, sync=lambda out: np.asarray(out[2])
+            runner, args, sync=lambda out: np.asarray(out[2]),
+            path="sharded_kfused", scheme="standard", k=k, n=problem.N,
         )
     )
     if sliced:
