@@ -104,6 +104,31 @@ def test_stop_resume_bitwise_across_paths():
     assert (resumed_k.abs_errors[:7] == 0).all()
 
 
+@pytest.mark.parametrize("timesteps,stop", [(21, 9), (17, 9)])
+def test_block_aligned_split_bitwise(timesteps, stop):
+    """A solve stopped on the block grid and resumed k-fused reproduces
+    the uninterrupted march's fields AND error rows bitwise, for odd (5)
+    and even (4) block counts in the uninterrupted march.  (Each part
+    keeps two blocks or more: on XLA's CPU backend a one-block march's
+    rel rows can land 1 ulp from the same block's in a longer march.)"""
+    p = Problem(N=16, timesteps=timesteps)
+    full = kfused.solve_kfused(p, k=4, interpret=True)
+    part = kfused.solve_kfused(p, k=4, stop_step=stop, interpret=True)
+    rest = kfused.resume_kfused(
+        p, part.u_prev, part.u_cur, start_step=stop, k=4, interpret=True
+    )
+    for name in ("u_prev", "u_cur"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(rest, name)), np.asarray(getattr(full, name))
+        )
+    for name in ("abs_errors", "rel_errors"):
+        want = getattr(full, name)
+        np.testing.assert_array_equal(getattr(part, name), want[:stop + 1])
+        np.testing.assert_array_equal(
+            getattr(rest, name)[stop + 1:], want[stop + 1:]
+        )
+
+
 def test_bf16_state_bitwise_vs_1step():
     """Per-substep quantization keeps bf16 k-fused bitwise equal to bf16
     1-step pallas, and the observed errors match its error pass."""

@@ -12,6 +12,7 @@ around them (an entry written here cannot be read back without a chip).
 
 import functools
 import os
+import re
 
 import pytest
 
@@ -97,6 +98,31 @@ def test_fused_kstep_comp(one_chip):
     _check(jax.jit(kstep).lower(
         field, field, field, plane, plane, _f32((K, N), one_chip)
     ).compile())
+
+
+@pytest.mark.parametrize("blocks", [7, 8])
+@pytest.mark.parametrize("scheme", ["standard", "compensated"])
+def test_solo_march_copies_no_state(one_chip, scheme, blocks):
+    """The solo k=4 march at N=64 (no 1-step tail) copies no (N, N, N)
+    state field in the compiled program, in any dtype: each kernel call's
+    outputs land in the loop's own buffers, for odd and even block
+    counts."""
+    from wavetpu.solver import kfused, kfused_comp
+
+    n = 64
+    problem = Problem(N=n, timesteps=1 + K * blocks)
+    if scheme == "standard":
+        runner, _ = kfused.make_kfused_solver(problem, k=K)
+    else:
+        runner, _ = kfused_comp.make_kfused_comp_solver(problem, k=K)
+    compiled = jax.jit(
+        lambda: runner(), out_shardings=one_chip
+    ).lower().compile()
+    _check(compiled)
+    copies = re.findall(
+        rf"= \w+\[{n},{n},{n}\]\S* copy\(", compiled.as_text()
+    )
+    assert not copies, copies
 
 
 def test_fused_kstep_comp_sharded_xy_four_chips(topo):

@@ -1,7 +1,7 @@
 """Compensated (velocity-form) temporally fused k-step solver.
 
 The round-4 flagship gap was fast OR accurate: the standard k-fused onion
-(solver/kfused.py) runs 42.6 Gcell/s at L-inf ~1.1e-3 (rounding-dominated),
+(solver/kfused.py) runs 71.8 Gcell/s at L-inf ~1.1e-3 (rounding-dominated),
 the 1-step compensated scheme 12.4 Gcell/s at 5.7e-6 (discretization-
 limited).  This module is both at once - the reference's own contract,
 whose flagship runs full speed at full accuracy (all-double,
@@ -18,9 +18,9 @@ standard onion - same HBM traffic for the pair - and the carry adds one
 slab-only stream (no halos: halo-cone carries seed to zero, a
 second-order approximation through the Laplacian; see
 `stencil_pallas._kstep_comp_kernel`).  Measured on v5e at N=512/1000,
-errors fused on every layer: 33.98 Gcell/s at L-inf 5.72e-6 (k=4, vs
-the 1-step compensated path's 12.4 Gcell/s at 5.69e-6 - 2.7x at equal
-accuracy; k=2 lands at 22.3).
+errors fused on every layer, the solve alone: 66.2 Gcell/s at L-inf
+5.72e-6 (k=4, bf16 carry), where the 1-step compensated path measured
+12.4 Gcell/s at 5.69e-6 and k=2 22.3.
 
 With `v_dtype=bfloat16` and `carry=False` the same march becomes the
 increment-form bf16 mode (BASELINE config 5 re-scoped to numbers that
@@ -28,7 +28,8 @@ mean something): the increment stream stores bf16, u stays the f32
 carrier, and the bf16 quantization error ~|v|*2^-8 per step stays far
 below the O(1) solution - unlike a bf16 u, whose per-step increments sit
 below the bf16 ulp and whose trajectory is garbage (round-4 BENCH: 0.66
-L-inf).  Measured: 44.19 Gcell/s at L-inf 6.39e-4 (k=4, N=512/1000).
+L-inf).  Measured: 73.1 Gcell/s at L-inf 6.39e-4 (k=4, N=512/1000, the
+solve alone).
 
 Unlike the standard k-fused path there is NO bitwise-parity claim against
 the 1-step scheme (intermediate layers skip the storage round-trip, halo
@@ -238,8 +239,10 @@ def _make_march(problem, dtype, v_dtype, carry_on, k, compute_errors,
             return (u2, v2, c2), (abs_e, rel_e)
 
         starts = start + k * jnp.arange(nblocks)
+        # Two blocks a loop turn, so that XLA copies no state field before
+        # each call: see kfused._make_march.
         (u, v, carry), (abs_b, rel_b) = lax.scan(
-            body, (u, v, carry), starts
+            body, (u, v, carry), starts, unroll=2
         )
         abs_parts = [abs_b.reshape(-1)]
         rel_parts = [rel_b.reshape(-1)]
