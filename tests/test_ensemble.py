@@ -3,7 +3,7 @@
 The load-bearing invariant: every lane of a batched solve is BITWISE
 identical to the same problem solved solo on the same path - including
 per-lane phases, per-lane stop layers (frozen by masking), per-lane
-c2tau2 fields, and batches padded with masked filler lanes.  A change to
+c2tau2 fields, and padded batches, masked or not.  A change to
 either the ensemble lane programs or the solo solvers that breaks these
 equalities is a correctness regression, not a tolerance issue.
 """
@@ -126,6 +126,94 @@ class TestPadding:
     def test_pad_below_batch_rejected(self, problem, lanes):
         with pytest.raises(ValueError, match="pad_to"):
             eb.solve_ensemble(problem, lanes, path="roll", pad_to=2)
+
+
+# Lanes of each case, padded to 4: (a) every lane runs to the last layer,
+# the unmasked march; (b) a real early stop beside a full lane, and (c)
+# one real lane stopping at layer 1, both masked.
+MARCH_CASES = {
+    "full": ([eb.LaneSpec(), eb.LaneSpec(phase=1.0),
+              eb.LaneSpec(phase=0.5)], False),
+    "mixed": ([eb.LaneSpec(phase=1.0, stop_step=5),
+               eb.LaneSpec(phase=0.5)], True),
+    "stop1": ([eb.LaneSpec(phase=0.5, stop_step=1)], True),
+}
+
+
+class TestLaneMarch:
+    """The standard 1-step lane program branches once a batch: unmasked
+    when every lane (padding included) runs to the last layer, masked
+    otherwise - each lane bitwise its solo solve either way."""
+
+    @pytest.mark.parametrize("case", sorted(MARCH_CASES))
+    @pytest.mark.parametrize("path", ["roll", "pallas"])
+    def test_padded_batch_matches_solo(self, problem, path, case):
+        from wavetpu.serve.engine import ServeEngine
+        from wavetpu.serve.scheduler import (
+            DynamicBatcher,
+            ServeMetrics,
+            SolveRequest,
+        )
+
+        lanes, masked = MARCH_CASES[case]
+        res = eb.solve_ensemble(
+            problem, lanes, path=path, interpret=True, pad_to=4
+        )
+        assert res.batch_size == 4
+        assert res.masked is masked
+        step = (stencil_pallas.make_step_fn(interpret=True)
+                if path == "pallas" else None)
+        solos = [
+            leapfrog.solve(
+                problem, step_fn=step, phase=lane.phase,
+                stop_step=lane.stop(problem),
+            )
+            for lane in lanes
+        ]
+        _assert_lane_parity(res, solos)
+
+        metrics = ServeMetrics()
+        batcher = DynamicBatcher(
+            ServeEngine(bucket_sizes=(4,), interpret=True),
+            metrics=metrics, max_wait=30.0, max_batch=len(lanes),
+        )
+        try:
+            futs = [
+                batcher.submit(
+                    SolveRequest(problem=problem, lane=lane, path=path)
+                )
+                for lane in lanes
+            ]
+            for fut, solo in zip(futs, solos):
+                got, err, info = fut.result(120)
+                assert err is None
+                assert info["batch_size"] == 4
+                assert _bitwise(got.u_cur, solo.u_cur)
+        finally:
+            batcher.close()
+        snap = metrics.snapshot()
+        assert snap["batches_total"] == 1
+        assert snap["masked_batches_total"] == int(masked)
+        assert snap["unmasked_batches_total"] == int(not masked)
+
+    def test_padding_takes_the_longest_real_stop(self, problem):
+        lanes = [eb.LaneSpec(stop_step=5), eb.LaneSpec(stop_step=3)]
+        res = eb.solve_ensemble(problem, lanes, path="roll", pad_to=4)
+        assert res.masked
+        assert eb.padding_lane(5).stop_step == 5
+        solver = eb.EnsembleSolver(problem, 4, path="roll")
+        full = [eb.LaneSpec()] * 3 + [eb.padding_lane(problem.timesteps)]
+        assert not solver.masked(full)
+        assert solver.masked(full[:3] + [eb.padding_lane()])
+
+    @pytest.mark.parametrize("path", ["kfused", "compensated"])
+    def test_other_lane_programs_always_mask(self, problem, path):
+        scheme = "compensated" if path == "compensated" else "standard"
+        solver = eb.EnsembleSolver(
+            problem, 2, path="kfused" if path == "kfused" else "roll",
+            k=2, scheme=scheme,
+        )
+        assert solver.masked([eb.LaneSpec(), eb.LaneSpec(phase=1.0)])
 
 
 class TestFields:

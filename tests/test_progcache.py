@@ -222,6 +222,27 @@ class TestCorruptionDrills:
         assert other.load(_key()) is None
         assert other.counts.get("disk_miss") == 1
 
+    def test_entry_from_an_older_version_is_not_adopted(
+        self, tmp_path, monkeypatch
+    ):
+        """An entry written under 0.1.0, whose lane programs always
+        masked, is never adopted: the version is in the fingerprint,
+        so the current one misses it and compiles its own program."""
+        import wavetpu
+
+        current = wavetpu.__version__
+        assert current != "0.1.0"
+        monkeypatch.setattr(wavetpu, "__version__", "0.1.0")
+        d, _ = self._warm_cache(tmp_path)
+        monkeypatch.setattr(wavetpu, "__version__", current)
+        eng = ServeEngine(bucket_sizes=(1,), interpret=True,
+                          program_cache_dir=d)
+        t = {}
+        result, _ = eng.solve(_tiny_problem(), [_lane()], timing=t)
+        assert t["warm"] == "false"
+        assert eng.progcache.counts.get("disk_miss") == 1
+        assert result.masked is False
+
 
 class TestGC:
     def test_over_budget_evicts_oldest_newest_survives(self, tmp_path):

@@ -225,7 +225,7 @@ class _FakeEngine:
         ]
         res = types.SimpleNamespace(
             results=results, n_lanes=len(lanes), batch_size=len(lanes),
-            batched=True, fallback_reason=None, path=path,
+            batched=True, fallback_reason=None, path=path, masked=False,
             solve_seconds=0.01, aggregate_gcells_per_second=1.0,
         )
         return res, [None] * len(lanes)
@@ -1671,9 +1671,16 @@ class TestDistributedTracingServe:
             chunk_steps=4, state_store=SolveStateStore(store_dir),
         )
         gauge = b.metrics._inflight_chunks
+        # Compile the chunk programs first: a cold compile takes about
+        # as long as the deadline, which would then expire before the
+        # first chunk and leave the gauge up for only a few ms.
+        req = _req(p)
+        eng.chunk_runner(
+            p, req.scheme, req.path, req.k, req.dtype_name, chunk_steps=4
+        )
         try:
             fut = b.submit(
-                _req(p), deadline=time.monotonic() + 0.4,
+                req, deadline=time.monotonic() + 0.4,
                 trace_context=origin,
             )
             # the gauge rises while the march is genuinely in flight...

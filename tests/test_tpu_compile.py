@@ -125,6 +125,100 @@ def test_solo_march_copies_no_state(one_chip, scheme, blocks):
     assert not copies, copies
 
 
+def _computations(text):
+    """HLO module text -> {computation name: its instruction lines}."""
+    out, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%(\S+) \(", line)
+        if m:
+            name = m.group(1)
+            out[name] = []
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+def _called(comps, root):
+    """`root` and every computation it calls, transitively."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += re.findall(
+                r"(?:calls|body|condition|to_apply)=%([\w.\-]+)", line
+            )
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                todo += [b.strip().lstrip("%") for b in group.split(",")]
+    return seen
+
+
+def _loop_lines(comps, branch):
+    """Instruction lines of the while-loop bodies reached from `branch`."""
+    bodies = [
+        body for name in _called(comps, branch) for line in comps[name]
+        for body in re.findall(r"body=%([\w.\-]+)", line)
+    ]
+    assert bodies, branch
+    return [line for body in bodies for name in _called(comps, body)
+            for line in comps[name]]
+
+
+def _lane_selects(lines, lanes):
+    """Selects whose predicate is one flag a lane: a pred[lanes]
+    broadcast along the batch axis (the per-lane stop mask)."""
+    defs = {line.split(" = ", 1)[0].strip(): line
+            for line in lines if " = " in line}
+    out = []
+    for line in lines:
+        m = re.search(r" select\((%[\w.\-]+),", line)
+        if m:
+            pred = defs.get(m.group(1), "")
+            if (re.search(rf"= pred\[{lanes},\S* broadcast\(", pred)
+                    and "dimensions={0}" in pred):
+                out.append(line.split(" = ", 1)[0].strip())
+    return out
+
+
+@pytest.mark.parametrize("compute_errors", [True, False])
+def test_lane_march_unmasked_branch(one_chip, compute_errors):
+    """The batched 1-step lane program (2 lanes, N=64, 9 marched layers)
+    branches once on "every lane runs to the last layer": the unmasked
+    branch's loop holds no per-lane select, the masked branch's loop
+    keeps its lane selects, and neither loop copies the (2, N, N, N)
+    state."""
+    from wavetpu.ensemble import batched
+
+    n, lanes = 64, 2
+    solver = batched.EnsembleSolver(
+        Problem(N=n, timesteps=10), lanes, path="pallas", interpret=False,
+        compute_errors=compute_errors,
+    )
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in solver._example_args()]
+    compiled = solver._runner.lower(*args).compile()
+    _check(compiled)
+    comps = _computations(compiled.as_text())
+    (branches,) = [
+        re.search(r"branch_computations=\{([^}]*)\}", line).group(1)
+        for lines in comps.values() for line in lines
+        if " conditional(" in line
+    ]
+    # lax.cond lowers to branch 0 = false (masked), 1 = true (unmasked).
+    masked, unmasked = (b.strip().lstrip("%") for b in branches.split(","))
+    state = re.compile(rf"= f32\[{lanes},{n},{n},{n}\]\S* copy\(")
+    loop = _loop_lines(comps, unmasked)
+    assert not _lane_selects(loop, lanes)
+    if not compute_errors:
+        assert not [line for line in loop if " select(" in line]
+    assert not [line for line in loop if state.search(line)]
+    loop = _loop_lines(comps, masked)
+    assert _lane_selects(loop, lanes)
+    assert not [line for line in loop if state.search(line)]
+
+
 def test_fused_kstep_comp_sharded_xy_four_chips(topo):
     """The y-sharded compensated onion (fused_kstep_comp_sharded_xy) in
     the (2,2,1) mesh program that runs it: bootstrap plus one k=4 block

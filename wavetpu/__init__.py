@@ -10,6 +10,9 @@ and halo exchange - expressed as one jitted program per chip with cyclic
 
 from wavetpu.core.problem import Problem, parse_length
 
-__version__ = "0.1.0"
+# Part of the disk program cache's fingerprint (serve/progcache.py):
+# bump it whenever a compiled program changes, so that entries written
+# by an older version are never adopted.
+__version__ = "0.2.0"
 
 __all__ = ["Problem", "parse_length", "__version__"]

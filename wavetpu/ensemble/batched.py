@@ -9,7 +9,8 @@ differs in
    the per-lane error oracle stays exact),
  * the number of layers marched (`LaneSpec.stop_step`: the batch marches
    to the max and earlier-stopping lanes are FROZEN by `where` masking,
-   which preserves their state bit-for-bit), and
+   which preserves their state bit-for-bit; a standard 1-step batch whose
+   every lane runs to `timesteps` marches without the mask), and
  * optionally a per-lane tau^2 c^2(x,y,z) field (no analytic oracle, so
    field batches require compute_errors=False).
 
@@ -76,13 +77,16 @@ class LaneSpec:
         )
 
 
-def padding_lane() -> LaneSpec:
-    """The masked filler lane the serve layer pads batches with: frozen
-    after layer 1 (stop=1 sits on every k-block grid), default phase.
-    Padding lanes ride the batch axis only - elementwise across lanes -
-    so real lanes are bitwise unchanged (tests/test_ensemble.py pins it).
+def padding_lane(stop_step: int = 1) -> LaneSpec:
+    """The filler lane batches are padded with: default phase, frozen
+    after layer `stop_step` (1, the default, sits on every k-block grid).
+    `solve_ensemble` passes the batch's largest real stop, so padding
+    never asks for a mask the real lanes do not (a batch of full-stop
+    requests marches unmasked).  Padding lanes ride the batch axis only -
+    elementwise across lanes - so real lanes are bitwise unchanged
+    (tests/test_ensemble.py pins it).
     """
-    return LaneSpec(stop_step=1)
+    return LaneSpec(stop_step=stop_step)
 
 
 @dataclasses.dataclass
@@ -94,6 +98,9 @@ class EnsembleResult:
     compiled program's lanes including padding, `n_lanes` the real ones.
     `solve_seconds` is the whole batch's wall time (each lane's
     SolveResult carries the same number: lanes finish together).
+    `masked` is True when the program froze lanes with per-step `where`
+    selects (`EnsembleSolver.masked`); False for an unmasked march and
+    for the lane-loop fallback's solo solves.
     """
 
     problem: Problem
@@ -111,6 +118,7 @@ class EnsembleResult:
     # would copy the whole batch state per request batch.
     u_prev_batch: Optional[object] = None
     u_cur_batch: Optional[object] = None
+    masked: bool = False
 
     @property
     def aggregate_gcells_per_second(self) -> float:
@@ -384,7 +392,10 @@ class EnsembleSolver:
     executes it on a packed batch and returns per-lane SolveResults.
 
     The lane program vmapped here mirrors the solo solver's op sequence
-    exactly; tests/test_ensemble.py pins bitwise lane parity.
+    exactly; tests/test_ensemble.py pins bitwise lane parity.  Lanes that
+    stop early are frozen by per-step `where` masks; the standard 1-step
+    program skips them when every lane, padding included, runs to
+    `timesteps` (`masked`).
     """
 
     def __init__(
@@ -459,7 +470,34 @@ class EnsembleSolver:
                 else self._onestep_lane(interpret, block_x)
             )
         in_axes = (0, 0, 0, 0) if with_field else (0, 0, 0)
-        self._runner = jax.jit(jax.vmap(lane_run, in_axes=in_axes))
+        if self._may_skip_mask:
+            # The batch-level "every lane runs to the last layer" flag
+            # rides unbatched (in_axes None), so vmap keeps the lane
+            # program's lax.cond a real conditional.
+            lanes_run = jax.vmap(lane_run, in_axes=(None,) + in_axes)
+            last = problem.timesteps
+
+            def batch_run(cts, stops, taylors, *field):
+                return lanes_run(
+                    jnp.all(stops == last), cts, stops, taylors, *field
+                )
+
+            self._runner = jax.jit(batch_run)
+        else:
+            self._runner = jax.jit(jax.vmap(lane_run, in_axes=in_axes))
+
+    @property
+    def _may_skip_mask(self) -> bool:
+        """Only the standard 1-step lane program has an unmasked march."""
+        return self.scheme == "standard" and self.path != "kfused"
+
+    def masked(self, lanes: Sequence[LaneSpec]) -> bool:
+        """Whether this (padded) batch marches with the per-step lane
+        masks: the predicate the program itself branches on."""
+        return not self._may_skip_mask or any(
+            lane.stop(self.problem) != self.problem.timesteps
+            for lane in lanes
+        )
 
     # ---- lane programs (solo op sequences with runtime ct tables) ----
 
@@ -475,40 +513,69 @@ class EnsembleSolver:
             problem, self.path, block_x, interpret, self.with_field
         )
 
-        def lane_run(ct_table, stop, taylor, *field):
+        def lane_run(full, ct_table, stop, taylor, *field):
             params = field[0] if self.with_field else params0
-            u0, u1 = _bootstrap(
-                problem, dtype, sx, sy, sz, ct_table, taylor, step, params
-            )
-            a0 = r0 = jnp.zeros((), f)
-            if compute_errors:
-                a1, r1 = errors(u1, 1, ct_table)
-            else:
-                a1 = r1 = jnp.zeros((), f)
 
-            def body(carry, n):
-                u_prev, u = carry
+            def layer(u_prev, u, n):
                 u_next = step(u_prev, u, problem, params)
+                if compute_errors:
+                    return u_next, errors(u_next, n, ct_table)
+                return u_next, (jnp.zeros((), f), jnp.zeros((), f))
+
+            def masked_body(carry, n):
+                u_prev, u = carry
+                u_next, (ae, re) = layer(u_prev, u, n)
                 live = n <= stop
                 if compute_errors:
-                    ae, re = errors(u_next, n, ct_table)
                     ae = jnp.where(live, ae, jnp.zeros((), f))
                     re = jnp.where(live, re, jnp.zeros((), f))
-                else:
-                    ae = re = jnp.zeros((), f)
                 return (
                     jnp.where(live, u, u_prev),
                     jnp.where(live, u_next, u),
                 ), (ae, re)
 
-            (u_prev, u_cur), (abs_t, rel_t) = lax.scan(
-                body, (u0, u1), jnp.arange(2, problem.timesteps + 1)
-            )
-            return (
-                u_prev,
-                u_cur,
-                jnp.concatenate([jnp.stack([a0, a1]), abs_t]),
-                jnp.concatenate([jnp.stack([r0, r1]), rel_t]),
+            def full_body(carry, n):
+                u_prev, u = carry
+                u_next, rows = layer(u_prev, u, n)
+                return (u, u_next), rows
+
+            def march(body, unroll):
+                u0, u1 = _bootstrap(
+                    problem, dtype, sx, sy, sz, ct_table, taylor, step,
+                    params,
+                )
+                a0 = r0 = jnp.zeros((), f)
+                if compute_errors:
+                    a1, r1 = errors(u1, 1, ct_table)
+                else:
+                    a1 = r1 = jnp.zeros((), f)
+                (u_prev, u_cur), (abs_t, rel_t) = lax.scan(
+                    body, (u0, u1), jnp.arange(2, problem.timesteps + 1),
+                    unroll=unroll,
+                )
+                return (
+                    u_prev,
+                    u_cur,
+                    jnp.concatenate([jnp.stack([a0, a1]), abs_t]),
+                    jnp.concatenate([jnp.stack([r0, r1]), rel_t]),
+                )
+
+            # One branch a batch, not one select a step: when every lane
+            # runs to the last layer none freezes, and the selects (three
+            # fields read and two written a step) only copy.  The full
+            # march takes three layers a loop turn: a step reads two
+            # fields and writes a third, so the state cycles through
+            # three buffers, and with fewer steps a turn XLA copies both
+            # fields back into the loop's own buffers every turn.  Each
+            # branch bootstraps its own layers 0/1: a loop started from
+            # the conditional's operands copied the masked march's state
+            # every step.  Same kernel, operands and order in both
+            # branches, so every lane stays bitwise what the masked march
+            # gives.
+            return lax.cond(
+                full,
+                lambda: march(full_body, 3),
+                lambda: march(masked_body, 1),
             )
 
         return lane_run
@@ -874,13 +941,13 @@ def run_batch(solver, lanes: Sequence[LaneSpec]):
     """`solver.run` of the vmapped and the sharded batched solvers: the
     host's lane packing (span `ensemble.pack`), then the execute through
     `block_until_ready` and the error-block readback (`ensemble.run`,
-    timed as solve_seconds)."""
+    timed as solve_seconds; attr `masked`: `solver.masked(lanes)`)."""
     import jax
 
     init_s = solver.compile()
     with tracing.span("ensemble.pack"):
         args = solver.pack(lanes)
-    with tracing.span("ensemble.run"):
+    with tracing.span("ensemble.run", masked=solver.masked(lanes)):
         t0 = time.perf_counter()
         out = solver._exec(*args)
         jax.block_until_ready(out)
@@ -1076,8 +1143,9 @@ def solve_ensemble(
     """Solve a batch of lanes as one vmapped program (or the recorded
     lane-loop fallback).
 
-    `pad_to` rounds the batch up to a program-cache bucket with masked
-    `padding_lane()`s (dropped from `results`).  Pass a pre-built
+    `pad_to` rounds the batch up to a program-cache bucket with
+    `padding_lane()`s that stop where the batch's longest real lane
+    does (dropped from `results`).  Pass a pre-built
     `solver` (the serve engine's cached program) to skip rebuilding; its
     geometry must match.
     """
@@ -1109,7 +1177,8 @@ def solve_ensemble(
             raise ValueError(
                 f"pad_to={pad_to} < {len(lanes)} real lanes"
             )
-        pad = [padding_lane()] * (pad_to - len(lanes))
+        stop = max(lane.stop(problem) for lane in lanes)
+        pad = [padding_lane(stop)] * (pad_to - len(lanes))
         batch = lanes + (fill_fields(problem, pad) if with_field else pad)
     if solver is None:
         solver = EnsembleSolver(
@@ -1130,4 +1199,5 @@ def solve_ensemble(
         solve_seconds=solve_s,
         u_prev_batch=outputs[0],
         u_cur_batch=outputs[1],
+        masked=solver.masked(batch),
     )

@@ -284,6 +284,11 @@ class ShardedEnsembleSolver:
         self.compile_seconds = time.perf_counter() - t0
         return self.compile_seconds
 
+    def masked(self, lanes: Sequence[LaneSpec]) -> bool:
+        """The sharded lane program masks every step, whatever the
+        stops (`batched.EnsembleSolver.masked`'s contract)."""
+        return True
+
     def run(self, lanes: Sequence[LaneSpec]):
         return run_batch(self, lanes)
 
@@ -431,4 +436,5 @@ def solve_ensemble_sharded(
         solve_seconds=solve_s,
         u_prev_batch=outputs[0],
         u_cur_batch=outputs[1],
+        masked=True,
     )

@@ -4,10 +4,12 @@ One compiled batched program serves every request that matches its
 identity: `ProgramKey` = the full problem geometry (N, Lx/y/z, T,
 timesteps), scheme, kernel path, k, dtype, whether lanes carry c2 fields,
 whether errors are computed, and the BATCH-SIZE BUCKET.  Requests are
-padded up to the nearest bucket with masked `padding_lane()`s (which
-provably leave real lanes bitwise unchanged - tests/test_ensemble.py), so
-a handful of buckets (default 1/2/4/8) covers every occupancy without
-per-batch recompilation.
+padded up to the nearest bucket with `padding_lane()`s that stop where
+the batch's longest request does (so a batch of full-length requests
+marches without the per-step lane mask), which provably leave real
+lanes bitwise unchanged - tests/test_ensemble.py - so a handful of
+buckets (default 1/2/4/8) covers every occupancy without per-batch
+recompilation.
 
 The cache is a plain LRU: `max_programs` compiled executables, eviction
 of the least-recently-used on overflow, hits/misses/evictions counted for

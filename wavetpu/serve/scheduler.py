@@ -210,7 +210,13 @@ class ServeMetrics:
         )
         self._padding = r.counter(
             "wavetpu_serve_padding_lanes_total",
-            "masked padding lanes marched (bucket size - occupancy)",
+            "padding lanes marched (bucket size - occupancy)",
+        )
+        self._lane_march = r.counter(
+            "wavetpu_serve_lane_march_batches_total",
+            "vmapped batches by lane march: masked (per-step lane-freeze "
+            "selects) or unmasked (every lane runs to the last layer)",
+            ("march",),
         )
         self._fallbacks = r.counter(
             "wavetpu_serve_fallback_batches_total",
@@ -432,9 +438,17 @@ class ServeMetrics:
                       cells: float, solve_seconds: float,
                       batch_size: Optional[int] = None,
                       queue_waits: Sequence[float] = (),
-                      request_ids: Sequence[Optional[str]] = ()) -> None:
+                      request_ids: Sequence[Optional[str]] = (),
+                      masked: Optional[bool] = None) -> None:
+        """`masked`: the vmapped batch's `EnsembleResult.masked`; None
+        (the lane-loop fallback, a chunked solo march) counts no lane
+        march."""
         with self.registry.lock:
             self._batches.inc()
+            if masked is not None:
+                self._lane_march.inc(
+                    march="masked" if masked else "unmasked"
+                )
             self._occupancy.observe(occupancy)
             if occupancy > self._occupancy_max.value():
                 self._occupancy_max.set(occupancy)
@@ -522,6 +536,12 @@ class ServeMetrics:
                 "rejected_total": int(self._rejected.value()),
                 "limit_rejected_total": int(self._limit_rejected.total()),
                 "padding_lanes_total": int(self._padding.value()),
+                "masked_batches_total": int(
+                    self._lane_march.value(march="masked")
+                ),
+                "unmasked_batches_total": int(
+                    self._lane_march.value(march="unmasked")
+                ),
                 "last_batch_age_seconds": (
                     None if age is None else round(age, 3)
                 ),
@@ -1443,6 +1463,7 @@ class DynamicBatcher:
             cells=cells, solve_seconds=result.solve_seconds,
             batch_size=result.batch_size, queue_waits=waits,
             request_ids=[item.request_id for item in batch],
+            masked=result.masked if result.batched else None,
         )
         padding_lanes = result.batch_size - result.n_lanes
         batch_info = {
@@ -1462,7 +1483,7 @@ class DynamicBatcher:
         # compile = the batch's cache-miss compile (0 warm), execute =
         # everything after batch formation minus that compile (device
         # march + watchdog + result plumbing), padding = the share of
-        # the batch's solve spent marching masked padding lanes -
+        # the batch's solve spent marching padding lanes -
         # informational waste attribution, a subset of execute, NOT an
         # additive wall-clock component.
         compile_s = float(timing.get("compile_seconds", 0.0))
