@@ -432,7 +432,7 @@ class _BreakerProbeEngine:
         ]
         res = types.SimpleNamespace(
             results=results, n_lanes=len(lanes), batch_size=len(lanes),
-            batched=True, fallback_reason=None, path=path, masked=False,
+            path=path, masked=False,
             solve_seconds=0.01, aggregate_gcells_per_second=1.0,
         )
         return res, [None] * len(lanes)

@@ -39,8 +39,6 @@ def lanes():
 
 
 def _assert_lane_parity(res, solos):
-    assert res.batched, res.fallback_reason
-    assert res.fallback_reason is None
     for got, solo in zip(res.results, solos):
         assert _bitwise(got.u_cur, solo.u_cur)
         assert _bitwise(got.u_prev, solo.u_prev)
@@ -237,7 +235,6 @@ class TestFields:
         res = eb.solve_ensemble(
             problem, lanes, path="roll", compute_errors=False
         )
-        assert res.batched
         solo0 = leapfrog.solve(
             problem, step_fn=stencil_ref.make_variable_c_step(field),
             compute_errors=False,
@@ -266,7 +263,6 @@ class TestFields:
             problem, [eb.LaneSpec(c2tau2_field=field), eb.LaneSpec()],
             path="pallas", compute_errors=False, interpret=True,
         )
-        assert res.batched
         solo = leapfrog.solve(
             problem,
             step_fn=stencil_pallas.make_step_fn(
@@ -281,7 +277,6 @@ class TestFields:
             problem, [eb.LaneSpec(c2tau2_field=field), eb.LaneSpec()],
             path="kfused", k=2, compute_errors=False, interpret=True,
         )
-        assert res.batched
         solo = kfused.solve_kfused(
             problem, k=2, interpret=True, compute_errors=False,
             c2tau2_field=field,
@@ -389,16 +384,19 @@ class TestCompensatedLaneParity:
             assert np.array_equal(a.abs_errors, b.abs_errors)
             assert np.array_equal(a.rel_errors, b.rel_errors)
 
-    def test_no_compensated_fallback_on_vmapping_backends(self, problem):
-        # Acceptance pin: fallback_reason must not mention the
-        # compensated scheme on any path that vmaps on this backend.
+    def test_compensated_batches_on_every_path(self, problem):
+        # Every path runs the compensated lane program itself: the
+        # batch's raw state carries the lane axis, and each lane's
+        # result is its row of it.
         for path, k in (("roll", 1), ("pallas", 1), ("kfused", 2)):
             res = eb.solve_ensemble(
                 problem, [eb.LaneSpec()], scheme="compensated",
                 path=path, k=k, interpret=True,
             )
-            assert res.batched, (path, res.fallback_reason)
-            assert res.fallback_reason is None
+            assert res.path == path and res.batch_size == 1
+            assert np.shape(res.u_cur_batch) == (1,) + (problem.N,) * 3
+            assert _bitwise(res.results[0].u_cur, res.u_cur_batch[0])
+            assert np.isfinite(res.results[0].abs_errors).all()
 
     def test_compensated_field_batch_rejected(self, problem):
         field = np.full((problem.N,) * 3, problem.a2tau2)
@@ -407,63 +405,6 @@ class TestCompensatedLaneParity:
                 problem, [eb.LaneSpec(c2tau2_field=field)],
                 scheme="compensated", path="roll", compute_errors=False,
             )
-
-
-class TestFallbacks:
-    def test_probe_failure_falls_back_with_reason(
-        self, problem, lanes, monkeypatch
-    ):
-        monkeypatch.setattr(
-            eb, "vmap_capability",
-            lambda *a, **k: (False, "forced-by-test"),
-        )
-        res = eb.solve_ensemble(problem, lanes, path="roll")
-        assert res.batched is False
-        assert "forced-by-test" in res.fallback_reason
-        # The fallback still honors per-lane identity.
-        solo = leapfrog.solve(problem, phase=1.0)
-        assert _bitwise(res.results[1].u_cur, solo.u_cur)
-
-    def test_compensated_probe_failure_lane_loop_honors_phase(
-        self, problem, monkeypatch
-    ):
-        # The lane-loop fallback for the compensated scheme must pass
-        # each lane's phase through to the solo compensated solver.
-        monkeypatch.setattr(
-            eb, "vmap_capability",
-            lambda *a, **k: (False, "forced-by-test"),
-        )
-        res = eb.solve_ensemble(
-            problem, [eb.LaneSpec(phase=1.0)], scheme="compensated",
-            path="kfused", k=2, interpret=True,
-        )
-        assert res.batched is False
-        solo = kfused_comp.solve_kfused_comp(
-            problem, k=2, interpret=True, phase=1.0
-        )
-        assert _bitwise(res.results[0].u_cur, solo.u_cur)
-
-    def test_probe_verdict_is_cached_per_scheme(self):
-        eb._PROBE_CACHE.clear()
-        try:
-            ok1, _ = eb.vmap_capability("roll", interpret=True)
-            assert ok1
-            assert len(eb._PROBE_CACHE) == 1
-            ok2, _ = eb.vmap_capability("roll", interpret=True)
-            assert ok2 and len(eb._PROBE_CACHE) == 1
-            # the compensated scheme probes (and caches) separately
-            ok3, _ = eb.vmap_capability(
-                "roll", interpret=True, scheme="compensated"
-            )
-            assert ok3 and len(eb._PROBE_CACHE) == 2
-            probes = eb.probe_results()
-            assert len(probes) == 2
-            assert {p["scheme"] for p in probes} == {
-                "standard", "compensated"
-            }
-            assert all(p["ok"] for p in probes)
-        finally:
-            eb._PROBE_CACHE.clear()
 
 
 class TestValidation:
@@ -563,7 +504,61 @@ class TestResultShape:
         assert len(res.results[2].abs_errors) == 5 + 1
         assert res.results[2].steps_computed == 5
 
+    def test_lane_results_are_the_batch_rows(self, problem, lanes):
+        # The lanes come from one split of the padded batch and one
+        # host copy of each error block: each is its own batch row.
+        res = eb.solve_ensemble(problem, lanes, path="roll", pad_to=4)
+        assert len(res.results) == 3
+        for i, r in enumerate(res.results):
+            assert _bitwise(r.u_prev, res.u_prev_batch[i])
+            assert _bitwise(r.u_cur, res.u_cur_batch[i])
+            for rows in (r.abs_errors, r.rel_errors):
+                assert isinstance(rows, np.ndarray)
+                assert rows.dtype == np.float64
+                assert len(rows) == r.steps_computed + 1
+
     def test_lane_spec_defaults(self, problem):
         lane = eb.LaneSpec()
         assert lane.stop(problem) == problem.timesteps
         assert dataclasses.replace(lane, stop_step=3).stop(problem) == 3
+
+
+def _refuse_build(*args, **kwargs):
+    raise RuntimeError("vmapped program refused to build")
+
+
+@pytest.mark.parametrize("entry", [
+    "solve_ensemble", "solve_ensemble_sharded", "engine", "engine_mesh",
+])
+def test_build_failure_is_an_error(entry, monkeypatch):
+    # A batched program that cannot be built is an error the caller
+    # sees: no other path answers in its place.  The engine feeds it to
+    # the circuit breaker, once, under the request's key.
+    from wavetpu.ensemble import sharded as es
+    from wavetpu.serve.engine import ServeEngine
+
+    monkeypatch.setattr(eb, "EnsembleSolver", _refuse_build)
+    monkeypatch.setattr(es, "ShardedEnsembleSolver", _refuse_build)
+    p = Problem(N=8, timesteps=4)
+    lanes = [eb.LaneSpec(), eb.LaneSpec(phase=1.0)]
+    mesh = (2, 2, 1)
+    with pytest.raises(RuntimeError, match="refused to build"):
+        if entry == "solve_ensemble":
+            eb.solve_ensemble(p, lanes, path="roll")
+        elif entry == "solve_ensemble_sharded":
+            es.solve_ensemble_sharded(p, lanes, mesh_shape=mesh)
+        else:
+            eng = ServeEngine(
+                bucket_sizes=(2,), interpret=True, breaker_threshold=1,
+                breaker_cooldown_s=60.0,
+            )
+            eng.solve(p, lanes, path="roll",
+                      mesh=mesh if entry == "engine_mesh" else None)
+    if entry.startswith("engine"):
+        (row,) = eng.breaker_stats()["keys"]
+        assert row["consecutive_failures"] == 1
+        assert "refused to build" in row["last_error"]
+        assert row["key"] == eng.breaker.describe(eng.breaker_key(
+            p, "standard", "roll", 4, "f32", False,
+            mesh if entry == "engine_mesh" else None,
+        ))

@@ -41,8 +41,6 @@ def lanes():
 
 
 def _assert_lane_parity(res, solos):
-    assert res.batched, res.fallback_reason
-    assert res.fallback_reason is None
     for got, solo in zip(res.results, solos):
         assert _bitwise(got.u_cur, solo.u_cur)
         assert _bitwise(got.u_prev, solo.u_prev)
@@ -66,10 +64,6 @@ class TestShardedLaneParity:
         _assert_lane_parity(res, solos)
 
     def test_pallas_on_221_mesh(self, problem, lanes):
-        ok, why = es.vmap_capability(MESH, kernel="pallas",
-                                     interpret=True)
-        if not ok:
-            pytest.skip(f"pallas sharded batching unavailable: {why}")
         res = es.solve_ensemble_sharded(
             problem, lanes, mesh_shape=MESH, kernel="pallas",
             interpret=True,
@@ -152,40 +146,6 @@ class TestSoloShardedPhase:
                 problem, mesh_shape=MESH, kernel="roll",
                 scheme="compensated", phase=1.0,
             )
-
-
-class TestShardedFallback:
-    def test_probe_failure_falls_back_with_reason(
-        self, problem, lanes, monkeypatch
-    ):
-        monkeypatch.setattr(
-            es, "vmap_capability",
-            lambda *a, **k: (False, "forced-by-test"),
-        )
-        res = es.solve_ensemble_sharded(
-            problem, lanes, mesh_shape=MESH, kernel="roll"
-        )
-        assert res.batched is False
-        assert "forced-by-test" in res.fallback_reason
-        solo = sharded.solve_sharded(
-            problem, mesh_shape=MESH, kernel="roll", phase=1.0
-        )
-        assert _bitwise(res.results[1].u_cur, solo.u_cur)
-
-    def test_probe_verdict_cached_and_surfaced(self):
-        es._PROBE_CACHE.clear()
-        try:
-            ok, why = es.vmap_capability((2, 1, 1), kernel="roll",
-                                         interpret=True)
-            assert ok, why
-            assert len(es._PROBE_CACHE) == 1
-            es.vmap_capability((2, 1, 1), kernel="roll", interpret=True)
-            assert len(es._PROBE_CACHE) == 1
-            rows = es.probe_results()
-            assert rows[0]["mesh"] == [2, 1, 1]
-            assert rows[0]["ok"] is True
-        finally:
-            es._PROBE_CACHE.clear()
 
 
 class TestShardedValidation:

@@ -5,7 +5,7 @@ must be bitwise identical to the 1-step pallas solve - same per-substep
 ops - and its in-kernel per-layer error factorization must reproduce the
 post-hoc oracle (verify/oracle.py) for every layer, including the
 intermediate layers that never reach HBM.  Interpret mode on the CPU
-backend (tests/conftest.py); on-chip throughput is bench.py's job.
+backend (tests/conftest.py); on-chip throughput is the benchmark's job.
 """
 
 import functools

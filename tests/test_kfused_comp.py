@@ -8,9 +8,9 @@ round-4 verdict prescribed: TOLERANCE parity against f64, plus
 self-consistency with the 1-step compensated scheme, plus exact resume
 on block-aligned boundaries.
 
-On-chip reference numbers (v5e, N=512/1000, errors fused): 33.98 Gcell/s
-at L-inf 5.72e-6 (k=4 f32) and 44.19 Gcell/s at 6.39e-4 (k=4 bf16
-increment form) - recorded in BENCH_r05.json.
+On-chip accuracy (v5e, N=512/1000, errors fused): L-inf 5.72e-6 at k=4
+f32 and 6.39e-4 at k=4 with the bf16 increment form; throughput is
+the benchmark's (BENCHMARK.json, PERF_LEDGER.jsonl).
 """
 
 import jax.numpy as jnp
@@ -148,7 +148,7 @@ def test_bf16_increment_form(problem, ref64):
     assert res.comp_carry is None
     # Measured 5.3e-4: the bf16 quantization of the increment stream is
     # bounded (~|v| * 2^-8 per step), unlike a bf16 carrier whose
-    # trajectory is garbage (0.66 at the flagship config, BENCH_r04).
+    # trajectory is garbage (0.66 at the flagship config on a v5e).
     diff = np.abs(np.asarray(res.u_cur, np.float64) - ref64).max()
     assert diff < 5e-3, diff
 

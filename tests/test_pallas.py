@@ -3,7 +3,8 @@
 The fused kernel (kernels/stencil_pallas.py) must agree with
 `stencil_ref.leapfrog_step` / `taylor_half_step` to rounding error on
 identical inputs (SURVEY.md section 4(e)).  Runs in interpret mode on the
-CPU test backend; the on-chip throughput side is bench.py's job.
+CPU test backend; on-chip throughput is the benchmark's job
+(BENCHMARK.json).
 """
 
 import jax.numpy as jnp

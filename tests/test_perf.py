@@ -32,8 +32,7 @@ def _fake_device(monkeypatch, platform, kind):
 
 class TestCostModel:
     @pytest.mark.parametrize("kw,want", [
-        # The bench-documented N=512 models, now this function's outputs
-        # (bench.py quotes these numbers in its row comments).
+        # The N=512 traffic models, per path.
         (dict(path="kfused", k=4, n=512), 8.0),
         (dict(path="kfused", k=2, n=512), 10.0),
         (dict(path="kfused", k=4, n=512, itemsize=2), 3.0),

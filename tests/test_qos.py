@@ -208,7 +208,7 @@ class _GateEngine:
         ]
         return types.SimpleNamespace(
             results=results, n_lanes=len(lanes), batch_size=len(lanes),
-            batched=True, fallback_reason=None, path=path, masked=False,
+            path=path, masked=False,
             solve_seconds=0.0, aggregate_gcells_per_second=1.0,
         ), [None] * len(lanes)
 

@@ -66,7 +66,7 @@ class _FakeEngine:
         ]
         res = types.SimpleNamespace(
             results=results, n_lanes=len(lanes), batch_size=len(lanes),
-            batched=True, fallback_reason=None, path=path, masked=False,
+            path=path, masked=False,
             solve_seconds=0.01, aggregate_gcells_per_second=1.0,
         )
         return res, [None] * len(lanes)
@@ -841,9 +841,8 @@ class TestChaosDrill:
                 "status", "report", "report_text", "batch"
             }
             assert set(payload["batch"]) == {
-                "occupancy", "batch_size", "batched", "fallback_reason",
-                "path", "padding_lanes", "aggregate_gcells_per_s",
-                "warm", "timing",
+                "occupancy", "batch_size", "path", "padding_lanes",
+                "aggregate_gcells_per_s", "warm", "timing",
             }
         finally:
             httpd.shutdown()

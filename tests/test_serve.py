@@ -78,7 +78,6 @@ class TestEngine:
         assert res.batch_size == 4
         assert res.n_lanes == 3
         assert health == [None, None, None]
-        assert res.batched
 
     def test_warmup_precompiles(self):
         eng = ServeEngine(bucket_sizes=(1, 2), interpret=True)
@@ -90,16 +89,14 @@ class TestEngine:
         assert eng.hits == 1  # served from the warmed program
 
     def test_compensated_scheme_batches_through_the_engine(self):
-        # The flagship scheme now rides the vmapped core: padded to the
-        # bucket, no fallback, each lane bitwise its solo solve.
+        # The flagship scheme rides the vmapped core: padded to the
+        # bucket, each lane bitwise its solo solve.
         eng = ServeEngine(bucket_sizes=(1, 2, 4), interpret=True)
         p = Problem(N=8, timesteps=5)
         lanes = [eb.LaneSpec(), eb.LaneSpec(phase=1.0)]
         res, health = eng.solve(
             p, lanes, scheme="compensated", path="kfused", k=2
         )
-        assert res.batched is True
-        assert res.fallback_reason is None
         assert res.batch_size == 2 and health == [None, None]
         from wavetpu.solver import kfused_comp
 
@@ -107,19 +104,6 @@ class TestEngine:
             p, k=2, interpret=True, phase=1.0
         )
         assert _bitwise(res.results[1].u_cur, solo.u_cur)
-
-    def test_vmap_probes_surface_in_cache_stats(self):
-        eng = ServeEngine(bucket_sizes=(1,), interpret=True)
-        p = Problem(N=8, timesteps=3)
-        eng.solve(p, [eb.LaneSpec()], scheme="compensated", path="roll")
-        probes = eng.cache_stats()["vmap_probes"]
-        assert any(
-            pr.get("scheme") == "compensated" and pr["path"] == "roll"
-            and pr["ok"] for pr in probes
-        )
-        # every probe row names its backend and carries an ok/reason pair
-        for pr in probes:
-            assert "backend" in pr and "ok" in pr and "reason" in pr
 
     def test_sharded_batched_program_cached_per_mesh_bucket(self):
         eng = ServeEngine(bucket_sizes=(1, 2), interpret=True)
@@ -130,7 +114,6 @@ class TestEngine:
             p, [eb.LaneSpec(), eb.LaneSpec(phase=1.0)], path="roll",
             mesh=(2, 2, 1),
         )
-        assert res.batched and res.fallback_reason is None
         assert health == [None, None]
         assert eng.hits == 1  # served from the warmed (mesh, bucket=2)
         keys = eng.cache_stats()["keys"]
@@ -225,7 +208,7 @@ class _FakeEngine:
         ]
         res = types.SimpleNamespace(
             results=results, n_lanes=len(lanes), batch_size=len(lanes),
-            batched=True, fallback_reason=None, path=path, masked=False,
+            path=path, masked=False,
             solve_seconds=0.01, aggregate_gcells_per_second=1.0,
         )
         return res, [None] * len(lanes)
@@ -595,7 +578,7 @@ class TestPreemptible:
         assert info["chunks"] == 4          # ceil(16 / 4)
         assert info["chunk_len"] == self.CHUNK
         assert info["resumed_from"] is None
-        assert info["occupancy"] == 1 and info["batched"] is True
+        assert info["occupancy"] == 1 and info["batch_size"] == 1
         assert res.final_step == p.timesteps
         # parity with the monolithic (vmapped, batch-of-1) serve path:
         # the chunked march is a latency/preemption trade, never an
@@ -792,7 +775,7 @@ class TestMetricsRegistryIntegration:
         m = ServeMetrics()
         m.observe_request()
         m.observe_response(True)
-        m.observe_batch(occupancy=3, batched=True, cells=1e9,
+        m.observe_batch(occupancy=3, cells=1e9,
                         solve_seconds=0.5, batch_size=4)
         m.observe_latency(0.1)
         snap = m.snapshot()
@@ -803,7 +786,9 @@ class TestMetricsRegistryIntegration:
         assert snap["batches_total"] == 1
         assert snap["batch_occupancy_mean"] == 3.0
         assert snap["batch_occupancy_max"] == 3
-        assert snap["fallback_batches"] == 0
+        # no `masked` passed (a chunked solo march): no lane march
+        assert snap["masked_batches_total"] == 0
+        assert snap["unmasked_batches_total"] == 0
         assert snap["latency_p50_ms"] == 100.0
         assert snap["aggregate_gcells_per_s"] == 2.0
         # new observability fields
@@ -819,7 +804,7 @@ class TestMetricsRegistryIntegration:
         can never read as "never executed"."""
         m = ServeMetrics()
         assert m.last_batch_age() is None
-        m.observe_batch(occupancy=1, batched=True, cells=1.0,
+        m.observe_batch(occupancy=1, cells=1.0,
                         solve_seconds=0.1)
         assert m.last_batch_age() is not None
         # even a zero timestamp is "has executed", not "never"
@@ -832,7 +817,7 @@ class TestMetricsRegistryIntegration:
             m.observe_request()
         m.observe_response(True)
         m.observe_response(False)
-        m.observe_batch(occupancy=2, batched=False, cells=2e9,
+        m.observe_batch(occupancy=2, cells=2e9,
                         solve_seconds=1.0, batch_size=2)
         m.observe_latency(0.2)
         snap = m.snapshot()
@@ -846,8 +831,8 @@ class TestMetricsRegistryIntegration:
             == snap["responses_error"] == 1
         assert samples["wavetpu_serve_batches_total"] == \
             snap["batches_total"] == 1
-        assert samples["wavetpu_serve_fallback_batches_total"] == \
-            snap["fallback_batches"] == 1
+        assert samples["wavetpu_serve_batch_occupancy_max"] == \
+            snap["batch_occupancy_max"] == 2
         # histogram triplet for the latency distribution
         assert samples["wavetpu_serve_request_seconds_count"] == 1
         assert samples["wavetpu_serve_request_seconds_sum"] == \
@@ -1403,14 +1388,6 @@ class TestHTTP:
         finally:
             state.draining = False
 
-    def test_metrics_exposes_vmap_probes(self, server):
-        base, _ = server
-        _post(base, {"N": 8, "timesteps": 4})
-        code, metrics = _get(base, "/metrics")
-        assert code == 200
-        probes = metrics["program_cache"]["vmap_probes"]
-        assert any(p.get("path") == "roll" and p["ok"] for p in probes)
-
     def test_mesh_request_serves_sharded_batched(self, server):
         base, _ = server
         code, body = _post(
@@ -1418,8 +1395,8 @@ class TestHTTP:
                    "phase": 1.0}, timeout=300,
         )
         assert code == 200
-        assert body["batch"]["batched"] is True
         assert "sharded(2, 2, 1)" in body["batch"]["path"]
+        assert set(body["batch"]) >= {"occupancy", "batch_size", "path"}
         assert body["report"]["final_step"] == 4
 
     def test_bad_request_400(self, server):

@@ -1264,8 +1264,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         )
         # Compile-cost ledger entry for the solo solve (no-op without
         # --telemetry-dir): `init_seconds` is the CLI's compile proxy -
-        # grid init + build + XLA compile - the same figure bench.py
-        # records as compile_seconds per row.
+        # grid init + build + XLA compile.
         from wavetpu.obs import ledger as _ledger
 
         if _ledger.enabled():

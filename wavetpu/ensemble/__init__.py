@@ -14,9 +14,7 @@ from wavetpu.ensemble.batched import (
     EnsembleResult,
     EnsembleSolver,
     LaneSpec,
-    probe_results,
     solve_ensemble,
-    vmap_capability,
 )
 from wavetpu.ensemble.sharded import (
     ShardedEnsembleSolver,
@@ -28,8 +26,6 @@ __all__ = [
     "EnsembleSolver",
     "LaneSpec",
     "ShardedEnsembleSolver",
-    "probe_results",
     "solve_ensemble",
     "solve_ensemble_sharded",
-    "vmap_capability",
 ]

@@ -27,14 +27,9 @@ suite green.  Compensated batches are constant-speed only (the solo
 velocity-form field path exists, but per-lane fields are not wired
 through the compensated vmapped core).
 
-Not every (scheme, path) vmaps on every backend (Mosaic's batching
-support for the onion kernels differs from interpret mode's).
-`vmap_capability` probes a tiny batched solve per (scheme, path,
-backend) once and caches the verdict; a failed probe drops to the
-LANE-LOOP fallback (sequential solo solves behind the same
-EnsembleResult interface) with the reason RECORDED in
-`EnsembleResult.fallback_reason`, and `probe_results()` exposes every
-cached verdict for GET /metrics.  Nothing falls back silently.
+Every batch runs as the vmapped program: a program that cannot be built
+or run raises, and the caller (the serve engine's breaker, a request's
+error answer) sees the error.
 
 Per-lane timestep masking on the "kfused" path freezes whole k-blocks, so
 a lane's stop_step must sit on the block grid ((stop-1) % k == 0) or be
@@ -44,6 +39,7 @@ the full march; the 1-step paths mask per layer.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import List, Optional, Sequence, Tuple
 
@@ -93,32 +89,28 @@ def padding_lane(stop_step: int = 1) -> LaneSpec:
 class EnsembleResult:
     """A batched solve's outcome: per-lane SolveResults + how it ran.
 
-    `batched` False means the lane-loop fallback executed (reason in
-    `fallback_reason` - never None in that case); `batch_size` counts the
-    compiled program's lanes including padding, `n_lanes` the real ones.
-    `solve_seconds` is the whole batch's wall time (each lane's
-    SolveResult carries the same number: lanes finish together).
-    `masked` is True when the program froze lanes with per-step `where`
-    selects (`EnsembleSolver.masked`); False for an unmasked march and
-    for the lane-loop fallback's solo solves.
+    `batch_size` counts the compiled program's lanes including padding,
+    `n_lanes` the real ones.  `solve_seconds` is the whole batch's wall
+    time (each lane's SolveResult carries the same number: lanes finish
+    together).  `masked` is True when the program froze lanes with
+    per-step `where` selects (`EnsembleSolver.masked`); False for an
+    unmasked march.
     """
 
     problem: Problem
     results: List["SolveResult"]  # noqa: F821 - from solver.leapfrog
     path: str
-    batched: bool
-    fallback_reason: Optional[str]
     batch_size: int
     n_lanes: int
     init_seconds: float
     solve_seconds: float
-    # The raw (B, N, N, N) batched state (padding lanes included; None
-    # on the lane-loop fallback).  The serve engine's per-lane watchdog
-    # reduces over these directly - re-stacking the per-lane views
-    # would copy the whole batch state per request batch.
-    u_prev_batch: Optional[object] = None
-    u_cur_batch: Optional[object] = None
-    masked: bool = False
+    # The raw (B, N, N, N) batched state (padding lanes included).  The
+    # serve engine's per-lane watchdog reduces over these directly -
+    # re-stacking the per-lane views would copy the whole batch state
+    # per request batch.
+    u_prev_batch: object
+    u_cur_batch: object
+    masked: bool
 
     @property
     def aggregate_gcells_per_second(self) -> float:
@@ -959,24 +951,42 @@ def run_batch(solver, lanes: Sequence[LaneSpec]):
     return out, init_s, solve_s
 
 
+@functools.lru_cache(maxsize=None)
+def _lane_splitter():
+    """One jitted dispatch that splits batched states into their lanes
+    (one program per batch shape, compiled with the bucket's first
+    batch)."""
+    import jax
+
+    return jax.jit(lambda *xs: tuple(
+        [x[i] for i in range(x.shape[0])] for x in xs
+    ))
+
+
 def _lane_results(problem, outputs, lanes, init_s, solve_s):
     """Per-lane SolveResults from batched outputs (padding already
     dropped by the caller passing only real lanes and their indices),
-    in the span `ensemble.results`."""
+    in the span `ensemble.results`.  The error blocks come to the host
+    once each (`run_batch` already read the first), and the states split
+    in one dispatch: per-lane slicing cost a dispatch, and a readback
+    for each error row, per lane."""
     from wavetpu.solver.leapfrog import SolveResult
 
     upb, ucb, ab, rb = outputs
     results = []
     with tracing.span("ensemble.results"):
+        ab = np.asarray(ab, np.float64)
+        rb = np.asarray(rb, np.float64)
+        ups, ucs = _lane_splitter()(upb, ucb)
         for i, lane in enumerate(lanes):
             s = lane.stop(problem)
             results.append(
                 SolveResult(
                     problem=problem,
-                    u_prev=upb[i],
-                    u_cur=ucb[i],
-                    abs_errors=np.asarray(ab[i], np.float64)[: s + 1],
-                    rel_errors=np.asarray(rb[i], np.float64)[: s + 1],
+                    u_prev=ups[i],
+                    u_cur=ucs[i],
+                    abs_errors=ab[i, : s + 1],
+                    rel_errors=rb[i, : s + 1],
                     init_seconds=init_s,
                     solve_seconds=solve_s,
                     steps_computed=s,
@@ -984,147 +994,6 @@ def _lane_results(problem, outputs, lanes, init_s, solve_s):
                 )
             )
     return results
-
-
-# ---- capability probe ----
-
-_PROBE_CACHE = {}
-
-
-def vmap_capability(
-    path: str,
-    k: int = 2,
-    interpret: Optional[bool] = None,
-    with_field: bool = False,
-    scheme: str = "standard",
-) -> Tuple[bool, Optional[str]]:
-    """Does jax.vmap compose with this (scheme, path) on this backend?
-
-    Runs a tiny batched solve (N=8, 2 lanes) end to end once per
-    (scheme, path, with_field, backend) and caches the verdict.  Returns
-    (ok, reason): reason is the exception summary on failure - the string
-    `solve_ensemble` records in `EnsembleResult.fallback_reason` so a
-    fallback is never silent.
-    """
-    import jax
-
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    key = (scheme, path, bool(with_field), bool(interpret),
-           jax.default_backend())
-    if key in _PROBE_CACHE:
-        return _PROBE_CACHE[key]
-    try:
-        tiny = Problem(N=8, timesteps=2 * max(2, k) + 1)
-        lanes = [LaneSpec(), LaneSpec(phase=1.0)]
-        if with_field:
-            lanes = fill_fields(tiny, lanes)
-        solver = EnsembleSolver(
-            tiny, len(lanes), path=path, k=min(k, 2) if path == "kfused"
-            else k, compute_errors=not with_field, interpret=interpret,
-            with_field=with_field, scheme=scheme,
-        )
-        out, _, _ = solver.run(lanes)
-        np.asarray(out[1])
-        verdict = (True, None)
-    except Exception as e:  # recorded, never raised: probe = capability
-        verdict = (False, f"{type(e).__name__}: {e}")
-    _PROBE_CACHE[key] = verdict
-    return verdict
-
-
-def probe_results() -> list:
-    """Every cached vmap-capability verdict, as dicts - the /metrics
-    surface that makes a chip silently serving lane-loop visible from
-    the outside (GET /metrics -> program_cache.vmap_probes)."""
-    return [
-        {
-            "scheme": k[0], "path": k[1], "with_field": k[2],
-            "interpret": k[3], "backend": k[4],
-            "ok": v[0], "reason": v[1],
-        }
-        for k, v in sorted(_PROBE_CACHE.items(), key=lambda kv: kv[0])
-    ]
-
-
-# ---- lane-loop fallback ----
-
-def _solve_lane_loop(
-    problem, lanes, dtype, scheme, path, k, compute_errors, interpret,
-    block_x, reason,
-):
-    """Sequential solo solves behind the EnsembleResult interface - the
-    recorded fallback when vmap does not compose on this backend."""
-    from wavetpu.kernels import stencil_pallas, stencil_ref
-    from wavetpu.solver import kfused, leapfrog
-
-    results = []
-    init_total = solve_total = 0.0
-    for lane in lanes:
-        s = lane.stop(problem)
-        if scheme == "compensated" and path == "kfused":
-            # The flagship velocity-form onion, lane by lane.
-            from wavetpu.solver import kfused_comp
-
-            res = kfused_comp.solve_kfused_comp(
-                problem, dtype=dtype, k=k,
-                compute_errors=compute_errors, stop_step=s,
-                interpret=interpret, phase=lane.phase,
-            )
-        elif scheme == "compensated":
-            comp_step = None
-            if path == "pallas":
-                comp_step = stencil_pallas.make_compensated_step_fn(
-                    interpret=interpret
-                )
-            res = leapfrog.solve_compensated(
-                problem, dtype=dtype, comp_step_fn=comp_step,
-                compute_errors=compute_errors, stop_step=s,
-                phase=lane.phase,
-            )
-        elif path == "kfused":
-            res = kfused.solve_kfused(
-                problem, dtype=dtype, k=k, compute_errors=compute_errors,
-                stop_step=s, block_x=block_x, interpret=interpret,
-                c2tau2_field=lane.c2tau2_field, phase=lane.phase,
-            )
-        else:
-            if lane.c2tau2_field is not None:
-                step_fn = (
-                    stencil_pallas.make_step_fn(
-                        block_x=block_x, interpret=interpret,
-                        c2tau2_field=lane.c2tau2_field,
-                    )
-                    if path == "pallas"
-                    else stencil_ref.make_variable_c_step(lane.c2tau2_field)
-                )
-            else:
-                step_fn = (
-                    stencil_pallas.make_step_fn(
-                        block_x=block_x, interpret=interpret
-                    )
-                    if path == "pallas"
-                    else None
-                )
-            res = leapfrog.solve(
-                problem, dtype=dtype, step_fn=step_fn,
-                compute_errors=compute_errors, stop_step=s,
-                phase=lane.phase,
-            )
-        init_total += res.init_seconds
-        solve_total += res.solve_seconds
-        results.append(res)
-    return EnsembleResult(
-        problem=problem,
-        results=results,
-        path=path,
-        batched=False,
-        fallback_reason=reason,
-        batch_size=len(lanes),
-        n_lanes=len(lanes),
-        init_seconds=init_total,
-        solve_seconds=solve_total,
-    )
 
 
 def solve_ensemble(
@@ -1140,8 +1009,7 @@ def solve_ensemble(
     pad_to: Optional[int] = None,
     solver: Optional[EnsembleSolver] = None,
 ) -> EnsembleResult:
-    """Solve a batch of lanes as one vmapped program (or the recorded
-    lane-loop fallback).
+    """Solve a batch of lanes as one vmapped program.
 
     `pad_to` rounds the batch up to a program-cache bucket with
     `padding_lane()`s that stop where the batch's longest real lane
@@ -1149,26 +1017,12 @@ def solve_ensemble(
     `solver` (the serve engine's cached program) to skip rebuilding; its
     geometry must match.
     """
-    import jax
     import jax.numpy as jnp
 
     dtype = jnp.float32 if dtype is None else dtype
     lanes = list(lanes)
     with_field = _validate(problem, lanes, path, k, compute_errors,
                            scheme)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    ok, why = vmap_capability(
-        path, k=k, interpret=interpret, with_field=with_field,
-        scheme=scheme,
-    )
-    if not ok:
-        return _solve_lane_loop(
-            problem, lanes, dtype, scheme, path, k, compute_errors,
-            interpret, block_x,
-            f"vmap capability probe failed on scheme {scheme!r} path "
-            f"{path!r}: {why}",
-        )
     if with_field:
         lanes = fill_fields(problem, lanes)
     batch = lanes
@@ -1191,8 +1045,6 @@ def solve_ensemble(
         problem=problem,
         results=_lane_results(problem, outputs, lanes, init_s, solve_s),
         path=path,
-        batched=True,
-        fallback_reason=None,
         batch_size=len(batch),
         n_lanes=len(lanes),
         init_seconds=init_s,

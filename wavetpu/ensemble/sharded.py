@@ -22,12 +22,6 @@ tables, the per-lane taylor/analytic bootstrap selector, and per-layer
 the 1-step kernel).  Per-lane c2tau2 fields are not wired (constant
 speed only); scheme is "standard" (the distributed velocity-form
 flagship still serves solo via solver/kfused_comp.py).
-
-`vmap_capability(mesh_shape, ...)` probes a tiny batched sharded solve
-once per (mesh, kernel, backend) and caches the verdict; a failed probe
-drops to the recorded lane-loop fallback (sequential solo sharded
-solves), reason in `EnsembleResult.fallback_reason` and visible in
-GET /metrics.
 """
 
 from __future__ import annotations
@@ -293,90 +287,6 @@ class ShardedEnsembleSolver:
         return run_batch(self, lanes)
 
 
-# ---- capability probe ----
-
-_PROBE_CACHE = {}
-
-
-def vmap_capability(
-    mesh_shape: Tuple[int, int, int],
-    kernel: str = "roll",
-    interpret: Optional[bool] = None,
-) -> Tuple[bool, Optional[str]]:
-    """Does shard_map-of-vmap compose on this (mesh, kernel, backend)?
-
-    Runs a tiny batched sharded solve once per key and caches the
-    verdict; `probe_results()` surfaces every cached entry for
-    GET /metrics alongside the single-device probes."""
-    import jax
-
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    key = (tuple(mesh_shape), kernel, bool(interpret),
-           jax.default_backend())
-    if key in _PROBE_CACHE:
-        return _PROBE_CACHE[key]
-    try:
-        tiny = Problem(N=8, timesteps=4)
-        lanes = [LaneSpec(), LaneSpec(phase=1.0)]
-        solver = ShardedEnsembleSolver(
-            tiny, len(lanes), mesh_shape, kernel=kernel,
-            interpret=interpret,
-        )
-        out, _, _ = solver.run(lanes)
-        np.asarray(out[1])
-        verdict = (True, None)
-    except Exception as e:  # recorded, never raised
-        verdict = (False, f"{type(e).__name__}: {e}")
-    _PROBE_CACHE[key] = verdict
-    return verdict
-
-
-def probe_results() -> list:
-    """Cached sharded vmap-capability verdicts as dicts (for /metrics)."""
-    return [
-        {
-            "mesh": list(k[0]), "kernel": k[1], "interpret": k[2],
-            "backend": k[3], "ok": v[0], "reason": v[1],
-        }
-        for k, v in sorted(_PROBE_CACHE.items(), key=lambda kv: str(kv[0]))
-    ]
-
-
-# ---- lane-loop fallback + entry point ----
-
-def _solve_lane_loop(problem, lanes, mesh_shape, dtype, kernel,
-                     compute_errors, interpret, devices, reason):
-    """Sequential solo sharded solves behind the EnsembleResult
-    interface - the recorded fallback when the composition does not
-    vmap on this backend."""
-    from wavetpu.solver import sharded
-
-    results = []
-    init_total = solve_total = 0.0
-    for lane in lanes:
-        res = sharded.solve_sharded(
-            problem, mesh_shape=mesh_shape, devices=devices, dtype=dtype,
-            compute_errors=compute_errors, kernel=kernel,
-            interpret=interpret, stop_step=lane.stop(problem),
-            phase=lane.phase,
-        )
-        init_total += res.init_seconds
-        solve_total += res.solve_seconds
-        results.append(res)
-    return EnsembleResult(
-        problem=problem,
-        results=results,
-        path=f"sharded{tuple(mesh_shape)}:{kernel}",
-        batched=False,
-        fallback_reason=reason,
-        batch_size=len(lanes),
-        n_lanes=len(lanes),
-        init_seconds=init_total,
-        solve_seconds=solve_total,
-    )
-
-
 def solve_ensemble_sharded(
     problem: Problem,
     lanes: Sequence[LaneSpec],
@@ -390,28 +300,15 @@ def solve_ensemble_sharded(
     solver: Optional[ShardedEnsembleSolver] = None,
 ) -> EnsembleResult:
     """Solve a batch of lanes as ONE sharded batched program over
-    `mesh_shape` (or the recorded lane-loop fallback).  Same padding /
-    pre-built-solver contract as `batched.solve_ensemble`; every lane is
-    bitwise equal to its solo `sharded.solve_sharded` on the same mesh
-    (u_prev/u_cur are the PADDED topo arrays, as the solo solver
-    returns them)."""
-    import jax
+    `mesh_shape`.  Same padding / pre-built-solver contract as
+    `batched.solve_ensemble`; every lane is bitwise equal to its solo
+    `sharded.solve_sharded` on the same mesh (u_prev/u_cur are the
+    PADDED topo arrays, as the solo solver returns them)."""
     import jax.numpy as jnp
 
     dtype = jnp.float32 if dtype is None else dtype
     lanes = list(lanes)
     _validate(problem, lanes, kernel, compute_errors)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    ok, why = vmap_capability(mesh_shape, kernel=kernel,
-                              interpret=interpret)
-    if not ok:
-        return _solve_lane_loop(
-            problem, lanes, mesh_shape, dtype, kernel, compute_errors,
-            interpret, devices,
-            f"sharded vmap capability probe failed on mesh "
-            f"{tuple(mesh_shape)} kernel {kernel!r}: {why}",
-        )
     batch = lanes
     if pad_to is not None:
         if pad_to < len(lanes):
@@ -428,8 +325,6 @@ def solve_ensemble_sharded(
         problem=problem,
         results=_lane_results(problem, outputs, lanes, init_s, solve_s),
         path=f"sharded{tuple(mesh_shape)}:{kernel}",
-        batched=True,
-        fallback_reason=None,
         batch_size=len(batch),
         n_lanes=len(lanes),
         init_seconds=init_s,

@@ -16,8 +16,7 @@ set of member urls known to hold them, learned from two sources:
    warm;desc=` label updates the table at traffic speed - `true`
    (memory hit), `disk` (adopted from the persistent cache), and
    `false` (it JUST paid the compile - warm from now on) all mark the
-   serving member a holder; `fallback` marks nothing (no batched
-   program was built).
+   serving member a holder; any other label marks nothing.
 
 Routing (`choose`): warm holders win; among several holders (or for a
 cold key) the least-loaded of TWO RANDOM CHOICES takes it - the

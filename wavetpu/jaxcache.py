@@ -8,7 +8,7 @@ timestamp - so that a second process of the same checkout finds what
 the first one compiled.
 
 Every entry point calls `configure()` before its first compile (the
-solo CLI, the serve front end, bench.py, chip_smoke.py); so does the
+solo CLI, the serve front end, the benchmark, chip_smoke.py); so does the
 serve engine's program cache, which needs to know whether the cache is
 on.  JAX decides at its first compile whether the cache is in use, so a
 later `configure()` resets that decision.

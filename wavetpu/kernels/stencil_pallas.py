@@ -692,8 +692,7 @@ def choose_kstep_block(
     N=512 f32 that admits k=2/bx=4 under the calibrated budget; k=4/bx=4
     models at ~134 MB against the 128 MiB physical - outside what this
     model will bless, but close enough to the measured ~5% overestimate
-    that `block_x=4` stays exposed for explicit on-chip attempts
-    (bench.py's kfused_varc row tries it and records the outcome).
+    that `block_x=4` stays exposed for explicit on-chip attempts.
     """
     if depth is None:
         depth = n

@@ -183,7 +183,7 @@ def compensated_step(u, v, carry, problem: Problem, coeff=None):
     but numerically far better in f32: the standard form adds the tiny
     update C*lap(u) (~1e-5 at N=512) into O(1) state and loses its low
     bits every step - measured 1.09e-3 L-inf error at N=512/1000 vs the
-    ~4e-6 discretization bound (BENCH_r03).  Here the increment
+    ~4e-6 discretization bound (v5e).  Here the increment
     accumulates in its own small-magnitude buffer and the u addition runs
     Kahan-compensated through `carry`, so rounding stays at the one-time
     f32 representation level (measured ~2e-7 vs f64 at N=128/1000 - a

@@ -164,9 +164,6 @@ def build_report(result, trace_path: Optional[str] = None,
             for name in after
             if name.startswith("wavetpu_serve_limit_rejected_total")
         )),
-        "fallback_batches": int(_delta(
-            after, before, "wavetpu_serve_fallback_batches_total"
-        )),
         # Cold-vs-warm program traffic during the replay window: misses
         # are FRESH compiles the replay paid, hits the warmed steady
         # state, disk_hits persistent-cache adoptions (a restarted

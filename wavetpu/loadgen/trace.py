@@ -229,9 +229,8 @@ def gen_tenants(duration: float, qps: float, scenarios: Sequence[dict],
     identity) at the remaining rate, every request `best_effort`.  Each
     record carries its tenant label (and api_key when given), so a
     replay through a quota-enforcing router shows the aggressor eating
-    429s while the victim's interactive p95 holds - the bench `qos`
-    row's and the CI QoS smoke's workload.  Deterministic in
-    (duration, qps, seed, scenarios)."""
+    429s while the victim's interactive p95 holds - the CI QoS smoke's
+    workload.  Deterministic in (duration, qps, seed, scenarios)."""
     rng = random.Random(seed)
     v_qps = max(qps * victim_frac, 1e-9)
     a_qps = max(qps - v_qps, 1e-9)

@@ -269,9 +269,9 @@ def observe_solve(result, path: str, *, scheme: str, k: int,
         return
     max_err = float(max(float(e) for e in errs))
     # The solver family's errors-off sentinel is an ALL-ZERO error
-    # array (bench.py's errors_computed contract): a measured max of
-    # exactly 0.0 is that sentinel, never a real oracle verdict -
-    # ledgering it would claim perfect accuracy for an unchecked solve.
+    # array: a measured max of exactly 0.0 is that sentinel, never a
+    # real oracle verdict - ledgering it would claim perfect accuracy
+    # for an unchecked solve.
     if max_err <= 0.0:
         return
     plan = make_plan(scheme, path, k, dtype_name(result.u_cur.dtype),
@@ -291,10 +291,9 @@ def observe_serve_batch(result, verdicts, *, scheme: str, k: int,
     """Per-lane accuracy recording off the serve engine's watchdog
     reduction: each HEALTHY lane that computed oracle errors records
     one ledger line + metric stamp for the plan that served it (the
-    batch's actual `result.path`, so a lane-loop fallback is labeled
-    as what ran).  Tripped lanes are excluded - their error fields are
-    poison, and their 422 already tells the story.  Caller guards
-    exceptions (the X-ray must never fail the batch)."""
+    batch's actual `result.path`).  Tripped lanes are excluded - their
+    error fields are poison, and their 422 already tells the story.
+    Caller guards exceptions (the X-ray must never fail the batch)."""
     plan = None
     for r, verdict in zip(result.results, verdicts):
         if verdict is not None:

@@ -1,6 +1,6 @@
 """Persistent compile-cost ledger + `wavetpu ledger-report`.
 
-BENCH_r04/r05 put compilation at 30-62 s against 2-7 s solves: for a
+Chip runs measured N=512 compiles at 30-62 s against 2-7 s solves: for a
 service, compile spend IS the dominant cold-start and autoscaling cost,
 and it is invisible across process restarts - every replica pays it
 again and nothing adds it up.  This module records every compile into

@@ -8,8 +8,7 @@ both gaps:
 
 ROOFLINE ATTRIBUTION.  `model_bytes_per_cell` is the ONE shared
 analytic cost model for every solver path - cells x steps x scheme x
-path x k x dtype -> bytes moved per cell-update - factored out of the
-per-row traffic models bench.py used to hard-code and reconciled with
+path x k x dtype -> bytes moved per cell-update - reconciled with
 `choose_kstep_block` / `choose_kstep_comp_block`'s VMEM accounting (the
 onion models read the SAME block depth the chooser blesses, so the
 modeled traffic follows the block the kernel actually runs).  From it,
@@ -129,10 +128,7 @@ def model_bytes_per_cell(
     depth: Optional[int] = None,
     ghosts: bool = False,
 ) -> Optional[float]:
-    """HBM bytes moved per cell-update under the path's traffic model.
-
-    The ONE source of truth for the per-row models bench.py documents
-    (its hard-coded numbers are now this function's outputs):
+    """HBM bytes moved per cell-update under the path's traffic model:
 
      * 1-step paths (`leapfrog`/`roll`/`pallas`/`sharded`, standard
        scheme): 3 state streams (u_prev + u in, u_next out) x itemsize,

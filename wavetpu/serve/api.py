@@ -7,9 +7,9 @@ Endpoints (contract in docs/serving.md):
                  Concurrent requests with the same program identity are
                  coalesced into one batched XLA solve (scheduler.py);
                  each response carries its lane's report plus batch
-                 context (occupancy, batched-or-fallback, path).  With
-                 --max-queue set, a full queue answers 429 (bounded-
-                 queue backpressure) instead of building latency.
+                 context (occupancy, path).  With --max-queue set, a
+                 full queue answers 429 (bounded-queue backpressure)
+                 instead of building latency.
                  Every response echoes the request id (`X-Request-Id`:
                  the caller's header if supplied, else server-minted
                  when tracing is on) and carries a `Server-Timing`
@@ -45,8 +45,8 @@ Endpoints (contract in docs/serving.md):
                  null/stale but draining false) from wedged; age is
                  null ONLY if no batch was ever executed.
   GET /metrics   request counts, batch occupancy, p50/p95 latency,
-                 aggregate Gcell/s, queue depth/rejections, program-
-                 cache and fallback state.  Content-negotiated: the
+                 aggregate Gcell/s, queue depth/rejections and
+                 program-cache state.  Content-negotiated: the
                  default is the historical JSON snapshot; `Accept:
                  text/plain` serves Prometheus text exposition and
                  `Accept: application/openmetrics-text` the OpenMetrics
@@ -306,7 +306,7 @@ def server_timing_header(timing: dict, total_s: float,
     (their sum ~= total up to parse/serialize overhead - the 10%
     contract tests/test_serve.py pins), padding is the informational
     masked-lane share of execute, total is the server-measured wall.
-    `warm` (the engine's true/disk/false/fallback program-source label)
+    `warm` (the engine's true/disk/false program-source label)
     rides as a desc-only entry - the fleet router reads it off each
     response to learn which replica holds which program without an
     extra /metrics round trip."""
@@ -331,8 +331,8 @@ class ServerState:
     size limits (413 / 422); `recorder` (a loadgen.trace.TraceRecorder)
     captures accepted /solve bodies into a replayable scenario trace;
     `server_timing=False` suppresses the Server-Timing response header
-    (ops escape hatch, and the A/B arm bench.py's loadgen observer-
-    overhead measurement compares against)."""
+    (ops escape hatch, and the arm an observer-overhead A/B measurement
+    compares against)."""
 
     def __init__(self, engine, batcher, metrics, default_kernel: str,
                  request_timeout: float = 600.0,
@@ -1015,13 +1015,11 @@ class _Handler(BaseHTTPRequestHandler):
             # A singleflight rider - the primary's answer fanned out to
             # this request; the primary stores, this one just says so.
             headers["X-Wavetpu-Cache"] = "coalesced"
-        elif batch_info.get("batched") and \
-                batch_info.get("fallback_reason") is None:
-            if st.result_cache.put(cache_key, body_bytes,
-                                   headers.get("Server-Timing")):
-                headers["X-Wavetpu-Cache"] = (
-                    f"store;fp={st.result_cache_fp_tag or 'none'}"
-                )
+        elif st.result_cache.put(cache_key, body_bytes,
+                                 headers.get("Server-Timing")):
+            headers["X-Wavetpu-Cache"] = (
+                f"store;fp={st.result_cache_fp_tag or 'none'}"
+            )
         return 200, body_bytes, headers
 
 
@@ -1422,14 +1420,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                                         pk.dtype, int(clen),
                                     )
                                     done += 1
-                                elif state.engine.program(
-                                    mp, pk.scheme, pk.path, pk.k,
-                                    pk.dtype, pk.with_field, pk.batch,
-                                    pk.mesh,
-                                ) is not None:
-                                    done += 1
                                 else:
-                                    skipped += 1
+                                    state.engine.program(
+                                        mp, pk.scheme, pk.path, pk.k,
+                                        pk.dtype, pk.with_field, pk.batch,
+                                        pk.mesh,
+                                    )
+                                    done += 1
                             except Exception as e:
                                 failed += 1
                                 print(f"manifest warmup key failed: "

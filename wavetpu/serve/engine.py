@@ -132,9 +132,6 @@ class ServeEngine:
         self._programs: "OrderedDict[ProgramKey, ensemble.EnsembleSolver]" = (
             OrderedDict()
         )
-        # path -> recorded fallback reason (never silent; surfaced in
-        # /metrics so an operator sees WHICH path refused to vmap).
-        self.fallbacks: dict = {}
         # Per-ProgramKey circuit breaker (None = disabled): K
         # consecutive compile/execute failures quarantine the key
         # bucket-wide; state rides both /metrics views.
@@ -220,11 +217,9 @@ class ServeEngine:
         mesh: Optional[Tuple[int, int, int]] = None,
     ):
         """The cached compiled program for this key, building (and
-        compiling) on miss - or None when the vmapped core cannot serve
-        the key (failed capability probe): the caller then runs the
-        recorded lane-loop fallback.  `mesh` selects the sharded x
-        batched composition (ensemble/sharded.py); a (mesh, bucket) pair
-        is its own cached executable."""
+        compiling) on miss; a build or compile error raises.  `mesh`
+        selects the sharded x batched composition (ensemble/sharded.py);
+        a (mesh, bucket) pair is its own cached executable."""
         return self._program(
             problem, scheme, path, k, dtype_name, with_field, batch, mesh
         )[0]
@@ -236,42 +231,23 @@ class ServeEngine:
     ):
         """`program()` plus THIS call's program-source attribution -
         (prog, source, compile_seconds) with source one of "memory"
-        (LRU hit), "disk" (persistent-cache adoption), "fresh" (paid
-        the XLA compile), or "fallback" (prog is None - capability-
-        refused, the caller runs the lane loop).  Per-call state, not a
-        counter diff - diffing the shared `misses` counter would race
-        with a concurrent warmup taking a miss on a different key.
-        `compile_seconds` is 0.0 on a memory hit or fallback, the
-        deserialize wall on a disk hit, and the measured build+compile
-        wall on a fresh compile - the `compile` component of the
-        response's Server-Timing header."""
+        (LRU hit), "disk" (persistent-cache adoption) or "fresh" (paid
+        the XLA compile).  Per-call state, not a counter diff - diffing
+        the shared `misses` counter would race with a concurrent warmup
+        taking a miss on a different key.  `compile_seconds` is 0.0 on
+        a memory hit, the deserialize wall on a disk hit, and the
+        measured build+compile wall on a fresh compile - the `compile`
+        component of the response's Server-Timing header."""
         compute_errors = self.compute_errors and not with_field
-        if mesh is not None:
-            if scheme != "standard":
-                # Refuse loudly: silently serving a compensated request
-                # with the standard scheme would be a wrong-result bug,
-                # not a fallback.  (The HTTP layer 400s this at parse;
-                # this guards direct ServeEngine users.)
-                raise ValueError(
-                    "sharded x batched serves the standard scheme only; "
-                    f"got scheme={scheme!r} with mesh {tuple(mesh)}"
-                )
-            ok, why = ens_sharded.vmap_capability(
-                mesh, kernel=path, interpret=self.interpret
+        if mesh is not None and scheme != "standard":
+            # Refuse loudly: silently serving a compensated request with
+            # the standard scheme would be a wrong-result bug.  (The
+            # HTTP layer 400s this at parse; this guards direct
+            # ServeEngine users.)
+            raise ValueError(
+                "sharded x batched serves the standard scheme only; "
+                f"got scheme={scheme!r} with mesh {tuple(mesh)}"
             )
-            if not ok:
-                self.fallbacks.setdefault(
-                    f"mesh:{tuple(mesh)}:{path}", why
-                )
-                return None, "fallback", 0.0
-        else:
-            ok, why = ensemble.vmap_capability(
-                path, k=k, interpret=self.interpret,
-                with_field=with_field, scheme=scheme,
-            )
-            if not ok:
-                self.fallbacks.setdefault(f"{scheme}:{path}", why)
-                return None, "fallback", 0.0
         key = ProgramKey.for_batch(
             problem, scheme, path, k, dtype_name, with_field,
             compute_errors, batch, mesh,
@@ -428,15 +404,14 @@ class ServeEngine:
         mesh: Optional[Tuple[int, int, int]] = None,
     ) -> List[int]:
         """AOT-compile the key for each requested bucket (default: all);
-        returns the bucket sizes actually warmed (empty when the path
-        falls back - recorded, not raised).  `mesh` warms the sharded x
+        returns the bucket sizes warmed.  `mesh` warms the sharded x
         batched (mesh, bucket) programs."""
         warmed = []
         for b in (self.bucket_sizes if batches is None else batches):
-            if self.program(
+            self.program(
                 problem, scheme, path, k, dtype_name, with_field, b, mesh
-            ) is not None:
-                warmed.append(b)
+            )
+            warmed.append(b)
         return warmed
 
     # ---- chunked long solves (serve/preempt.py) ----
@@ -609,7 +584,6 @@ class ServeEngine:
                         if self.progcache is not None else []
                     ),
                 },
-                "fallbacks": dict(self.fallbacks),
                 # Disk tier (serve/progcache.py): entry count/bytes,
                 # event counts, and the once-per-process AOT
                 # serialization probe verdict.
@@ -617,13 +591,6 @@ class ServeEngine:
                     self.progcache.stats()
                     if self.progcache is not None
                     else {"enabled": False}
-                ),
-                # Every cached vmap-capability verdict (single-device +
-                # sharded): a chip silently serving lane-loop is visible
-                # from the outside via these.
-                "vmap_probes": (
-                    ensemble.probe_results()
-                    + ens_sharded.probe_results()
                 ),
             }
 
@@ -645,27 +612,12 @@ class ServeEngine:
             "serve.watchdog", lanes=len(result.results)
         ) as sp:
             # One fused pass per state array over the whole batch (B
-            # scalars to host), not B separate reductions.  The vmapped
-            # path hands us its raw batched outputs (no copy); the
-            # lane-loop fallback has separate per-lane arrays and pays
-            # one stack each.
-            if result.u_prev_batch is not None:
-                amaxes = [
-                    health.guarded_amax_per_lane(
-                        batch
-                    )[: len(result.results)]
-                    for batch in (result.u_prev_batch, result.u_cur_batch)
-                ]
-            else:
-                import jax.numpy as jnp
-
-                amaxes = [
-                    health.guarded_amax_per_lane(
-                        jnp.stack([getattr(r, name)
-                                   for r in result.results])
-                    )
-                    for name in ("u_prev", "u_cur")
-                ]
+            # scalars to host), not B separate reductions, on the raw
+            # batched outputs (no copy).
+            amaxes = [
+                health.guarded_amax_per_lane(batch)[: len(result.results)]
+                for batch in (result.u_prev_batch, result.u_cur_batch)
+            ]
             out = []
             for amax in map(max, zip(*amaxes)):
                 amax = float(amax)
@@ -691,14 +643,14 @@ class ServeEngine:
         timing: Optional[dict] = None,
         feed_breaker: bool = True,
     ) -> Tuple[ensemble.EnsembleResult, List[Optional[str]]]:
-        """Pad to the bucket, run the cached program (or the recorded
-        fallback), watchdog each lane; returns (EnsembleResult,
-        per-lane health).  `mesh` routes the batch through the sharded x
-        batched composition.  `timing`, when a dict is passed, is filled
-        in place with `compile_seconds` (this call's cache-miss compile,
-        0.0 warm) and `warm` ("true"/"false"/"fallback") - the
-        scheduler threads it into each response's Server-Timing header
-        without changing this method's return contract.
+        """Pad to the bucket, run the cached program, watchdog each
+        lane; returns (EnsembleResult, per-lane health).  `mesh` routes
+        the batch through the sharded x batched composition.  `timing`,
+        when a dict is passed, is filled in place with
+        `compile_seconds` (this call's cache-miss compile, 0.0 warm)
+        and `warm` ("true"/"false"/"disk") - the scheduler threads it
+        into each response's Server-Timing header without changing this
+        method's return contract.
         `feed_breaker=False` (a batch of only shadow-solve lanes,
         serve/shadow.py) skips the circuit breaker entirely - neither
         admitted against an open key nor recorded on failure, so the
@@ -724,22 +676,17 @@ class ServeEngine:
             # Warm-vs-cold attribution: a solve whose program lookup had
             # to compile is this key's first-request latency, not its
             # steady state; the histogram label keeps the two
-            # populations apart.  A capability-refused key runs the
-            # lane-loop fallback, whose per-lane compile behavior is
-            # jax-cache-dependent - its own label value, so fallback
-            # outliers never pollute either the warm or the cold
-            # batched population.
+            # populations apart.
             prog, source, compile_seconds = self._program(
                 problem, scheme, path, k, dtype_name, with_field, bucket,
                 mesh
             )
-            warm = prog is not None and source == "memory"
+            warm = source == "memory"
             # "disk" is its own label: a persistent-cache adoption pays
             # deserialize (ms) where a cold compile pays XLA (s) - the
             # two populations must not share a histogram bucket.
             warm_label = (
-                "fallback" if prog is None
-                else "true" if warm
+                "true" if warm
                 else "disk" if source == "disk" else "false"
             )
             if timing is not None:
@@ -754,8 +701,7 @@ class ServeEngine:
                         problem, lanes, mesh_shape=mesh,
                         dtype=self._dtype(dtype_name), kernel=path,
                         compute_errors=compute_errors,
-                        interpret=self.interpret,
-                        pad_to=bucket if prog is not None else None,
+                        interpret=self.interpret, pad_to=bucket,
                         solver=prog,
                     )
                 else:
@@ -764,10 +710,8 @@ class ServeEngine:
                         scheme=scheme, path=path, k=k,
                         compute_errors=compute_errors,
                         interpret=self.interpret, block_x=self.block_x,
-                        pad_to=bucket if prog is not None else None,
-                        solver=prog,
+                        pad_to=bucket, solver=prog,
                     )
-                sp["batched"] = result.batched
                 # Roofline attribution for the batch program: the
                 # vmapped march moves batch_size x the per-lane traffic
                 # (padding lanes stream bytes too), so the program-level
@@ -816,10 +760,6 @@ class ServeEngine:
         if self.breaker is not None and bkey is not None:
             self.breaker.record_success(bkey)
         self._h_execute.observe(result.solve_seconds, warm=warm_label)
-        if not result.batched and result.fallback_reason:
-            self.fallbacks.setdefault(
-                f"{scheme}:{result.path}", result.fallback_reason
-            )
         # Chaos seam: execute-NaN poisons the batch's final state AFTER
         # the solve - the per-lane watchdog below must catch it (422s),
         # exactly as it would a real device fault.
@@ -829,15 +769,9 @@ class ServeEngine:
         ):
             import numpy as np
 
-            if result.u_cur_batch is not None:
-                result.u_cur_batch = np.full(
-                    np.shape(result.u_cur_batch), np.nan, np.float32
-                )
-            else:
-                for r in result.results:
-                    r.u_cur = np.full(
-                        np.shape(r.u_cur), np.nan, np.float32
-                    )
+            result.u_cur_batch = np.full(
+                np.shape(result.u_cur_batch), np.nan, np.float32
+            )
         verdicts = self.lane_health(result)
         # Accuracy observatory: every HEALTHY lane that computed oracle
         # errors stamps its measured max_abs_err and appends one

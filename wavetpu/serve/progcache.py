@@ -1,6 +1,6 @@
 """Persistent AOT program cache: compiled programs survive restarts.
 
-BENCH_r04/r05 and the compile ledger put XLA compiles at 30-62 s
+Chip runs and the compile ledger put N=512 XLA compiles at 30-62 s
 against 2-7 s solves - for a serving fleet, compilation is the dominant
 cold-start and autoscaling cost, and every process restart pays it
 again.  This module is the disk tier under the serve engine's in-memory
@@ -139,8 +139,8 @@ def aot_capability() -> Tuple[bool, Optional[str]]:
     """Can this jaxlib serialize, deserialize, AND execute a compiled
     executable?  Probed once per process with a tiny jit through the
     same `serialize_executable` / `load_executable` pair the engines
-    store and adopt with (the `vmap_capability` discipline: record the
-    verdict, never raise), and surfaced in /metrics via
+    store and adopt with (the verdict is recorded, never raised), and
+    surfaced in /metrics via
     `probe_results()` - a replica silently running the XLA-cache
     fallback must be visible from the outside."""
     global _AOT_PROBE

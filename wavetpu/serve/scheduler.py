@@ -218,10 +218,6 @@ class ServeMetrics:
             "selects) or unmasked (every lane runs to the last layer)",
             ("march",),
         )
-        self._fallbacks = r.counter(
-            "wavetpu_serve_fallback_batches_total",
-            "batches served by the lane-loop fallback",
-        )
         self._cells = r.counter(
             "wavetpu_serve_cells_total", "cell updates served"
         )
@@ -434,15 +430,14 @@ class ServeMetrics:
         rate = lanes / span
         return min(60.0, max(1.0, (pending + 1) / rate))
 
-    def observe_batch(self, occupancy: int, batched: bool,
-                      cells: float, solve_seconds: float,
+    def observe_batch(self, occupancy: int, cells: float,
+                      solve_seconds: float,
                       batch_size: Optional[int] = None,
                       queue_waits: Sequence[float] = (),
                       request_ids: Sequence[Optional[str]] = (),
                       masked: Optional[bool] = None) -> None:
         """`masked`: the vmapped batch's `EnsembleResult.masked`; None
-        (the lane-loop fallback, a chunked solo march) counts no lane
-        march."""
+        (a chunked solo march) counts no lane march."""
         with self.registry.lock:
             self._batches.inc()
             if masked is not None:
@@ -454,8 +449,6 @@ class ServeMetrics:
                 self._occupancy_max.set(occupancy)
             if batch_size is not None and batch_size > occupancy:
                 self._padding.inc(batch_size - occupancy)
-            if not batched:
-                self._fallbacks.inc()
             self._cells.inc(cells)
             self._solve_seconds.inc(solve_seconds)
             self._last_batch_ts.set(time.time())
@@ -522,7 +515,6 @@ class ServeMetrics:
                 "batches_total": batches,
                 "batch_occupancy_mean": mean_occ,
                 "batch_occupancy_max": int(self._occupancy_max.value()),
-                "fallback_batches": int(self._fallbacks.value()),
                 "latency_p50_ms": None if p50 is None else round(
                     p50 * 1e3, 3
                 ),
@@ -1450,7 +1442,7 @@ class DynamicBatcher:
             compile_ledger.clear_request_context()
         t_done = time.monotonic()
         tracing.end_span(
-            span, batch_size=result.batch_size, batched=result.batched,
+            span, batch_size=result.batch_size,
             padding_lanes=result.batch_size - result.n_lanes,
             solve_seconds=round(result.solve_seconds, 6),
         )
@@ -1459,18 +1451,16 @@ class DynamicBatcher:
             for r in result.results
         )
         self.metrics.observe_batch(
-            occupancy=result.n_lanes, batched=result.batched,
-            cells=cells, solve_seconds=result.solve_seconds,
+            occupancy=result.n_lanes, cells=cells,
+            solve_seconds=result.solve_seconds,
             batch_size=result.batch_size, queue_waits=waits,
             request_ids=[item.request_id for item in batch],
-            masked=result.masked if result.batched else None,
+            masked=result.masked,
         )
         padding_lanes = result.batch_size - result.n_lanes
         batch_info = {
             "occupancy": result.n_lanes,
             "batch_size": result.batch_size,
-            "batched": result.batched,
-            "fallback_reason": result.fallback_reason,
             "path": result.path,
             "padding_lanes": padding_lanes,
             "aggregate_gcells_per_s": round(
@@ -1780,7 +1770,7 @@ class DynamicBatcher:
         )
         cells = req.problem.cells_per_step * marched
         self.metrics.observe_batch(
-            occupancy=1, batched=True, cells=cells,
+            occupancy=1, cells=cells,
             solve_seconds=cp.execute_s, batch_size=1,
             queue_waits=[cp.wait_s],
             request_ids=[item.request_id],
@@ -1802,8 +1792,6 @@ class DynamicBatcher:
         return {
             "occupancy": 1,
             "batch_size": 1,
-            "batched": True,
-            "fallback_reason": None,
             "path": item.request.path,
             "padding_lanes": 0,
             "aggregate_gcells_per_s": round(agg, 4),

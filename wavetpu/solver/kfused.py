@@ -33,7 +33,7 @@ with the 1-step variable-c path (tests/test_kfused_varc.py).  The field
 onion's VMEM cost caps the block choice (choose_kstep_block field=True:
 k=2/bx=4 at N=512 under the calibrated budget; k=4/bx=4 models ~5% over
 the physical ceiling and stays reachable via an explicit block_x for
-on-chip attempts - bench.py's kfused_varc row records the outcome).
+on-chip attempts).
 There is no analytic oracle for variable c, so a field requires
 compute_errors=False.  The compensated (Kahan) scheme takes the field
 through solver/kfused_comp.py's velocity-form onion.
